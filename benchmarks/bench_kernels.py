@@ -55,7 +55,7 @@ def main():
     q = jnp.asarray(r.normal(size=(2, 512, 8, 64)), jnp.float32)
     k = jnp.asarray(r.normal(size=(2, 512, 2, 64)), jnp.float32)
     v = jnp.asarray(r.normal(size=(2, 512, 2, 64)), jnp.float32)
-    o1 = flash_attention(q, k, v, interpret=True)
+    o1 = flash_attention(q, k, v)
     o2 = ref.flash_attention_ref(q, k, v)
     err = float(jnp.abs(o1 - o2).max())
     us = _time(lambda: ref.flash_attention_ref(q, k, v))

@@ -170,7 +170,8 @@ def make_mesh(cfg: Union[AggregationConfig, FLConfig, None] = None,
     Flat -> 1-D ``(clients,)`` over all devices.  Hierarchical -> 2-D
     ``(region, clients)`` with ``n_regions`` region groups (``n_regions=0``
     picks the largest divisor of the device count that is <= sqrt(devices),
-    so an 8-device host becomes the 2x4 edge/region grid).
+    so an 8-device host becomes the 2x4 edge/region grid).  ``devices``
+    (default: all of them) picks the devices the mesh spans.
     """
     if cfg is None:
         cfg = AggregationConfig()
@@ -178,11 +179,12 @@ def make_mesh(cfg: Union[AggregationConfig, FLConfig, None] = None,
         cfg = cfg.aggregation_config
     n_dev = len(jax.devices() if devices is None else devices)
     if cfg.kind == "flat":
-        return jax.make_mesh((n_dev,), ("clients",))
+        return jax.make_mesh((n_dev,), ("clients",), devices=devices)
     r = cfg.n_regions
     if r == 0:
         r = max(d for d in range(1, int(n_dev ** 0.5) + 1) if n_dev % d == 0)
     if n_dev % r:
         raise ValueError(f"n_regions={r} does not divide device count "
                          f"{n_dev}")
-    return jax.make_mesh((r, n_dev // r), ("region", "clients"))
+    return jax.make_mesh((r, n_dev // r), ("region", "clients"),
+                         devices=devices)

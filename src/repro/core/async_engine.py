@@ -78,7 +78,6 @@ from repro.core import secure_agg as secure_agg_mod
 from repro.core import server_opt as server_opt_mod
 from repro.core import transforms as transforms_mod
 from repro.core.client import local_update
-from repro.sharding import shard_map
 
 PyTree = Any
 
@@ -161,7 +160,7 @@ def make_sharded_client_deltas(mesh, cfg: ForecasterConfig, loss: Callable,
             return client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
                                  cfg, loss, tcfg, cell_impl)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), pspec, pspec, pspec, pspec, P(), P()),
             out_specs=(pspec, pspec),
@@ -173,7 +172,7 @@ def make_sharded_client_deltas(mesh, cfg: ForecasterConfig, loss: Callable,
                              cfg, loss, tcfg, cell_impl, scfg, round_key,
                              w_full, slots)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         secure_body, mesh=mesh,
         in_specs=(P(), pspec, pspec, pspec, pspec, pspec, P(), P(), P(),
                   P()),

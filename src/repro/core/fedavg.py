@@ -54,7 +54,7 @@ from typing import Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import checkpoint as checkpoint_mod
 from repro.analysis import taint as taint_mod
@@ -70,7 +70,6 @@ from repro.core import transforms as transforms_mod
 from repro.core.client import local_update
 from repro.data import partition, windows
 from repro.models import forecaster
-from repro.sharding import shard_map
 
 
 # ------------------------------------------------------------- aggregation
@@ -154,7 +153,7 @@ def make_sharded_round(mesh, cfg: ForecasterConfig, loss: Callable,
         return new_params, loss_mean
 
     pspec = P(client_axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         round_body, mesh=mesh,
         in_specs=(P(), pspec, pspec, pspec, P()),
         out_specs=(P(), P()),
@@ -191,7 +190,7 @@ def make_sharded_engine_round(mesh, cfg: ForecasterConfig, loss: Callable,
         return w_agg, loss_mean
 
     pspec = P(client_axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         round_body, mesh=mesh,
         in_specs=(P(), pspec, pspec, pspec, pspec, P(), P()),
         out_specs=(P(), P()),
@@ -361,7 +360,7 @@ def make_pipeline_round(mesh, cfg: ForecasterConfig, loss: Callable,
                                   prox_mu, cfg=cfg, loss=loss,
                                   cell_impl=cell_impl, tcfg=tcfg, agg=agg)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             round_body, mesh=mesh,
             in_specs=(P(), pspec, pspec, pspec, pspec, pspec, P(), P()),
             out_specs=(P(), P()),
@@ -375,7 +374,7 @@ def make_pipeline_round(mesh, cfg: ForecasterConfig, loss: Callable,
                               scfg=scfg, round_key=round_key, slots=slots,
                               w_full=w_full)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         secure_body, mesh=mesh,
         in_specs=(P(), pspec, pspec, pspec, pspec, pspec, pspec, P(), P(),
                   P(), P()),
@@ -433,11 +432,15 @@ class RoundEngine:
                     "(build one with aggregation.make_mesh); the vmap path "
                     "has no reduction topology")
             self._sharded = None
+            self._client_sharding = None
         else:
             self._sharded = make_pipeline_round(
                 mesh, fcfg, self.loss, self.transform,
                 flcfg.aggregation_config, cell_impl=cell_impl,
                 scfg=self.secure)
+            self._client_sharding = NamedSharding(
+                mesh, aggregation_mod.make_aggregator(
+                    flcfg.aggregation_config, mesh).pspec())
         # ---- round pacing (sync vs semi-sync buffered) -------------------
         # the latency model is host-side only: under mode="sync" it just
         # tracks a simulated wall clock and never touches the round math
@@ -499,6 +502,16 @@ class RoundEngine:
         """Fresh global params + server-optimizer state."""
         params = forecaster.init_forecaster(key, self.fcfg)
         return params, server_opt_mod.init_server_state(params)
+
+    def put_clients(self, *arrays):
+        """Copy client-stacked host arrays to the device(s) of this
+        engine's round: on a mesh each array lands already split over the
+        client axes (one transfer per shard), never whole on the first
+        chip."""
+        if self._client_sharding is None:
+            return tuple(jnp.asarray(a) for a in arrays)
+        return tuple(jax.device_put(a, self._client_sharding)
+                     for a in arrays)
 
     def select(self, rng, members: np.ndarray, m: int, round_idx: int,
                weights: Optional[np.ndarray] = None) -> np.ndarray:
@@ -655,7 +668,7 @@ class RoundEngine:
 # ------------------------------------------------------------------ driver
 @dataclasses.dataclass
 class FLResult:
-    params: Dict
+    params: Dict                            # left on the last round's device(s)
     loss_history: np.ndarray
     cluster_centroids: Optional[np.ndarray] = None
     cluster_assignments: Optional[np.ndarray] = None  # (N,); -1 = held out
@@ -943,9 +956,8 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
             w = c_sel.copy()
             w[len(sel):] = 0.0                        # mask padding clients
             params, sstate, l = engine.step(
-                params, sstate, jnp.asarray(x), jnp.asarray(y),
-                jnp.asarray(bidx[pad_idx]), w, round_idx=t,
-                stream=cid if cid >= 0 else 0)
+                params, sstate, *engine.put_clients(x, y, bidx[pad_idx]), w,
+                round_idx=t, stream=cid if cid >= 0 else 0)
             hist.append(float(l))
             sim_hist.append(engine.sim_time)
             eps_hist.append(engine.accountant.epsilon())
@@ -963,7 +975,7 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
                 _save(cid, params, sstate, hist, sim_hist, eps_hist, t + 1)
             if stopped:
                 break
-        results[cid] = FLResult(jax.device_get(params), np.array(hist),
+        results[cid] = FLResult(params, np.array(hist),
                                 cents, assigns,
                                 held_ids if len(held_ids) else None,
                                 sim_times=np.array(sim_hist),

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,11 +75,10 @@ def make_sharded_outer(mesh, cfg: LocalSGDConfig, axis: str = "pod"):
     per pod on a leading axis of size ``mesh.shape[axis]``; that axis is
     sharded over ``axis`` so each pod sees only its own slice, and the
     cross-pod ``pmean`` inside :func:`outer_step` does the actual averaging.
-    The outer state and returned anchor are replicated (version-portable via
-    ``repro.sharding.shard_map``)."""
+    The outer state and returned anchor are replicated."""
     def body(stacked_local_params, state):
         mine = jax.tree.map(lambda w: w[0], stacked_local_params)
         return outer_step(mine, state, cfg, axis)
 
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                             out_specs=(P(), P()), check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                                 out_specs=(P(), P()), check_vma=False))

@@ -1,4 +1,7 @@
 """Pallas TPU kernels for the compute hot-spots: fused LSTM/GRU cells (the
 paper's edge training inner loop) and flash attention (the assigned archs'
-prefill).  Validated in interpret mode on CPU against ref.py oracles."""
+prefill).  Compiled by Mosaic on a TPU and interpreted on any other backend,
+chosen at trace time from the platform (``platform.py``); validated against
+the ref.py oracles in interpret mode on CPU and compiled for v5e by
+``tests/test_tpu_compile.py``."""
 from repro.kernels import ops, ref

@@ -16,11 +16,14 @@ VMEM budget; bigger bq amortizes the q load when hd is small.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -83,8 +86,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                                              "block_k", "interpret", "scale"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale=None, block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
-    """q: (B, S, Hq, hd); k, v: (B, S, Hkv, hd) -> (B, S, Hq, hd)."""
+                    interpret: Optional[bool] = None):
+    """q: (B, S, Hq, hd); k, v: (B, S, Hkv, hd) -> (B, S, Hq, hd).
+    ``interpret=None`` lets the platform decide."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     scale = scale if scale is not None else hd ** -0.5
@@ -116,6 +120,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

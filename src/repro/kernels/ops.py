@@ -1,8 +1,9 @@
 """Jit'd wrappers that route model code through the Pallas kernels.
 
-On CPU the kernels run in interpret mode (Python-level execution of the
-kernel body) — correctness only.  On TPU set ``REPRO_PALLAS_COMPILE=1`` (or
-call with interpret=False) to lower them for real.
+Whether a kernel is compiled or interpreted is decided when it is traced,
+from the platform (``kernels/platform.py``): compiled by Mosaic on a TPU,
+interpreted (Python-level execution of the kernel body, correctness only)
+on any other backend.  No wrapper here takes an ``interpret`` argument.
 
 The recurrent-cell wrappers are differentiable: ``pallas_call`` has no
 autodiff rule, so each cell carries a ``custom_vjp`` whose forward is the
@@ -13,8 +14,6 @@ run end-to-end with ``cell_impl="pallas"``.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
 from repro.kernels import flash_attention as _fa
@@ -22,16 +21,17 @@ from repro.kernels import gru_cell as _gru
 from repro.kernels import lstm_cell as _lstm
 from repro.kernels import ref as _ref
 
-_INTERPRET = (jax.default_backend() == "cpu"
-              and not os.environ.get("REPRO_PALLAS_COMPILE"))
+# TPU tiling: the last two dims of every block must divide by (8, 128) or
+# equal the array's dims — batch rows ride the sublanes, hidden units the
+# lanes
+_SUBLANE, _LANE = 8, 128
 
 
 @jax.custom_vjp
 def _lstm_cell_ad(x, h, c, wx, wh, b):
     return _lstm.lstm_cell(x, h, c, wx, wh, b,
-                           block_b=_pick_block(x.shape[0]),
-                           block_h=_pick_block(h.shape[-1]),
-                           interpret=_INTERPRET)
+                           block_b=_pick_block(x.shape[0], _SUBLANE),
+                           block_h=_pick_block(h.shape[-1], _LANE))
 
 
 def _lstm_cell_ad_fwd(x, h, c, wx, wh, b):
@@ -49,9 +49,8 @@ _lstm_cell_ad.defvjp(_lstm_cell_ad_fwd, _lstm_cell_ad_bwd)
 @jax.custom_vjp
 def _gru_cell_ad(x, h, wx, wh, b):
     return _gru.gru_cell(x, h, wx, wh, b,
-                         block_b=_pick_block(x.shape[0]),
-                         block_h=_pick_block(h.shape[-1]),
-                         interpret=_INTERPRET)
+                         block_b=_pick_block(x.shape[0], _SUBLANE),
+                         block_h=_pick_block(h.shape[-1], _LANE))
 
 
 def _gru_cell_ad_fwd(x, h, wx, wh, b):
@@ -76,10 +75,10 @@ def lstm_cell_fused(x_t, h, c, p, *, block_b=None, block_h=None):
     """
     if block_b or block_h:
         B, H = h.shape
-        bb = block_b or _pick_block(B)
-        bh = block_h or _pick_block(H)
+        bb = block_b or _pick_block(B, _SUBLANE)
+        bh = block_h or _pick_block(H, _LANE)
         return _lstm.lstm_cell(x_t, h, c, p["wx"], p["wh"], p["b"],
-                               block_b=bb, block_h=bh, interpret=_INTERPRET)
+                               block_b=bb, block_h=bh)
     return _lstm_cell_ad(x_t, h, c, p["wx"], p["wh"], p["b"])
 
 
@@ -87,23 +86,24 @@ def gru_cell_fused(x_t, h, p, *, block_b=None, block_h=None):
     """Drop-in for models.forecaster.gru_cell: (x_t, h, params) -> h'."""
     if block_b or block_h:
         B, H = h.shape
-        bb = block_b or _pick_block(B)
-        bh = block_h or _pick_block(H)
+        bb = block_b or _pick_block(B, _SUBLANE)
+        bh = block_h or _pick_block(H, _LANE)
         return _gru.gru_cell(x_t, h, p["wx"], p["wh"], p["b"],
-                             block_b=bb, block_h=bh, interpret=_INTERPRET)
+                             block_b=bb, block_h=bh)
     return _gru_cell_ad(x_t, h, p["wx"], p["wh"], p["b"])
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
                     block_q=128, block_k=128):
     return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                               scale=scale, block_q=block_q, block_k=block_k,
-                               interpret=_INTERPRET)
+                               scale=scale, block_q=block_q, block_k=block_k)
 
 
-def _pick_block(n: int, target: int = 128) -> int:
-    """Largest divisor of n that is ≤ target."""
-    for b in range(min(n, target), 0, -1):
+def _pick_block(n: int, align: int, target: int = 128) -> int:
+    """Block size along a dim of ``n``: the largest multiple of ``align``
+    that divides ``n`` and is ≤ ``target``, else the whole dim (always a
+    legal TPU block)."""
+    for b in range(min(n, target) // align * align, 0, -align):
         if n % b == 0:
             return b
-    return 1
+    return n

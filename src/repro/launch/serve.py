@@ -23,6 +23,7 @@ import numpy as np
 from repro.configs.base import FLConfig, ForecasterConfig
 from repro.core import fedavg
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import forecaster
 from repro.serving import (ClusterRouter, ModelRegistry, ServingEngine,
                            bucket_for)
@@ -53,7 +54,7 @@ def serve_forecaster(params, cfg: ForecasterConfig, requests: np.ndarray,
     return np.concatenate(outs)
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--state", default="CA")
     ap.add_argument("--train-clients", type=int, default=24)
@@ -69,7 +70,8 @@ def main():
     ap.add_argument("--max-batch", type=int, default=256)
     ap.add_argument("--min-bucket", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     fcfg = ForecasterConfig()
     flcfg = FLConfig(n_clients=args.train_clients,

@@ -21,9 +21,10 @@ from repro.core import clustering, fedavg
 from repro.core.sampling import SAMPLING_STRATEGIES
 from repro.core.server_opt import SERVER_OPTS
 from repro.data import synthetic, windows
+from repro.launch.compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--state", default="CA", choices=list(synthetic.STATES))
     ap.add_argument("--clients", type=int, default=100)
@@ -51,7 +52,8 @@ def main():
     ap.add_argument("--days", type=int, default=365)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     fcfg = ForecasterConfig(cell=args.cell, hidden_dim=args.hidden)
     flcfg = FLConfig(

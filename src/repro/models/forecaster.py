@@ -7,7 +7,8 @@ the paper's 8-step look-back / 4-step (1 h) horizon.
 The recurrent cells are written so the per-step compute is one fused function
 of ``(x_t, state, params)``; ``cell_impl="jnp"`` uses the pure-jnp path (the
 oracle), ``cell_impl="pallas"`` routes through the fused Pallas TPU cell in
-``repro.kernels`` (interpret mode on CPU).
+``repro.kernels`` — compiled on a TPU, interpreted on other backends, as the
+platform decides when the forward is traced.
 """
 from __future__ import annotations
 

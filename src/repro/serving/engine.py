@@ -18,9 +18,6 @@ the engine owns everything between that and the jit-compiled forward:
   consumer's min-max stats, normalizes INSIDE the jitted forward, and
   de-normalizes the forecast back to kWh — the jit boundary sees only
   fixed-shape f32 buffers, and callers never touch model space.
-* **Buffer donation**: on accelerator backends the padded input buffers are
-  donated to XLA (they are dead after the call), saving one device copy per
-  batch.  CPU does not implement donation, so it is off there by default.
 * **Hot-swap safety**: a flush snapshots its :class:`ModelHandle` ONCE and
   serves the whole batch from it; a registry publish lands at the next
   flush boundary, never mid-batch.  Model parameters are TRACED jit
@@ -110,7 +107,8 @@ class EngineStats:
 
 
 # jit bodies are module-level so every engine shares one trace per
-# (shape-bucket, cfg, weights) — engines only differ in donation policy
+# (shape-bucket, cfg, weights).  Nothing is donated: no input buffer has the
+# (B, horizon) output's shape, so XLA could reuse none of them.
 def _forecast_kwh(params, x, lo, hi, cfg):
     """(B, L) raw watt-hours + per-row (lo, hi) stats -> (B, H) kWh."""
     scale = jnp.maximum(hi - lo, 1e-9)
@@ -140,8 +138,7 @@ class ServingEngine:
 
     def __init__(self, registry: ModelRegistry, router=None, *,
                  max_batch: int = 256, min_bucket: int = 8,
-                 auto_flush: bool = True, donate: Optional[bool] = None,
-                 consumer_cache: int = 100_000):
+                 auto_flush: bool = True, consumer_cache: int = 100_000):
         for name, v in (("max_batch", max_batch), ("min_bucket", min_bucket)):
             if v < 1 or v & (v - 1):
                 raise ValueError(f"{name}={v} must be a power of two")
@@ -157,13 +154,8 @@ class ServingEngine:
             OrderedDict()
         self._consumer_cache = int(consumer_cache)
         self._last_gen: Dict[Any, int] = {}
-        if donate is None:                  # CPU has no donation support
-            donate = jax.default_backend() != "cpu"
-        kw: dict = dict(static_argnames=("cfg",))
-        if donate:
-            kw["donate_argnums"] = (1, 2, 3)      # x, lo, hi die with the call
-        self._fp32 = jax.jit(_forecast_kwh, **kw)
-        self._int8 = jax.jit(_forecast_kwh_int8, **kw)
+        self._fp32 = jax.jit(_forecast_kwh, static_argnames=("cfg",))
+        self._int8 = jax.jit(_forecast_kwh_int8, static_argnames=("cfg",))
 
     # -------------------------------------------------------------- probes
     def jit_cache_size(self) -> int:

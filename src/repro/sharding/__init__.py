@@ -1,6 +1,5 @@
-from repro.sharding.compat import shard_map
 from repro.sharding.rules import (ShardingRules, active_rules, constrain,
                                   constrain_heads, use_rules)
 
 __all__ = ["ShardingRules", "active_rules", "constrain", "constrain_heads",
-           "shard_map", "use_rules"]
+           "use_rules"]

@@ -1,9 +1,11 @@
 #!/usr/bin/env bash
 # Tier-1 test entry point.
 #
-# Forces 8 virtual CPU devices BEFORE jax initializes so the multi-device
-# shard_map tests (clients sharded over a real >1-device mesh) actually
-# exercise cross-shard psum aggregation on a laptop/CI box (olmax idiom).
+# Runs on the CPU, also on a machine with a TPU: the golden pins hold CPU
+# numerics, and the chip belongs to chip_smoke.py.  Forces 8 virtual CPU
+# devices BEFORE jax initializes so the multi-device shard_map tests
+# (clients sharded over a real >1-device mesh) actually exercise
+# cross-shard psum aggregation on a laptop/CI box (olmax idiom).
 #
 #   ./test.sh                 # fast default suite (slow tests deselected)
 #                             # + 1-round streaming-scalability bench smoke
@@ -12,6 +14,7 @@
 #   ./test.sh tests/test_server_opt.py -k shard_map
 set -euo pipefail
 cd "$(dirname "$0")"
+export JAX_PLATFORMS=cpu
 export XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}"
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 

@@ -7,7 +7,7 @@ import dataclasses
 import jax
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import (AsyncConfig, FLConfig, ForecasterConfig,
                                 LatencyConfig)

@@ -1,7 +1,7 @@
 """Synthetic corpus calibration, windowing correctness, K-means invariants."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import clustering
 from repro.data import partition, synthetic, windows

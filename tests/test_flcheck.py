@@ -24,7 +24,6 @@ from repro.analysis.dtypes import check_source as dt_check
 from repro.analysis.prng_lint import check_source as prng_check
 from repro.configs.base import SecureAggConfig, TransformConfig
 from repro.core import transforms as transforms_mod
-from repro.sharding import shard_map
 
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures", "flcheck")
 # pretend scope paths: FLC004/FLC005 only fire under core/, FLC006-FLC009
@@ -230,9 +229,9 @@ def test_taint_rejects_mask_after_psum():
         summed = jax.tree.map(lambda d: jax.lax.psum(d, "clients"), deltas)
         return jax.vmap(stack)(summed, keys)
 
-    fn = shard_map(broken, mesh=mesh,
-                   in_specs=(P("clients"), P("clients")),
-                   out_specs=P("clients"), check_vma=False)
+    fn = jax.shard_map(broken, mesh=mesh,
+                       in_specs=(P("clients"), P("clients")),
+                       out_specs=P("clients"), check_vma=False)
     with taint.analysis_mode():
         jx = jax.make_jaxpr(fn)(jnp.zeros((8, 3)),
                                 jnp.zeros((8, 2), jnp.uint32))

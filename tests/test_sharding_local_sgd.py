@@ -8,7 +8,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import local_sgd, sarima
 from repro.data import synthetic
 from repro.launch import costmodel
-from repro.sharding import ShardingRules, constrain, shard_map, use_rules
+from repro.sharding import ShardingRules, constrain, use_rules
 from repro.sharding.rules import safe_spec
 
 
@@ -52,8 +52,8 @@ def test_fedavg_outer_is_pmean():
         return local_sgd.fedavg_outer(p, "pod")
 
     p = {"w": jnp.arange(4.0)}
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(),
-                                out_specs=P()))(p)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(),
+                                    out_specs=P()))(p)
     np.testing.assert_allclose(out["w"], p["w"])          # 1 pod: identity
 
 
@@ -70,8 +70,8 @@ def test_outer_step_plain_fedavg_semantics():
         new_anchor, _ = local_sgd.outer_step(local_p, st, cfg, "pod")
         return new_anchor
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P(),
-                                out_specs=P()))(local)
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(),
+                                    out_specs=P()))(local)
     np.testing.assert_allclose(out["w"], 2.0)             # = mean of locals
 
 
@@ -88,8 +88,8 @@ def test_outer_momentum_accumulates():
         return a1, a2
 
     local = {"w": jnp.ones(2)}
-    a1, a2 = jax.jit(shard_map(f, mesh=mesh, in_specs=P(),
-                                   out_specs=P()))(local)
+    a1, a2 = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(),
+                                       out_specs=P()))(local)
     assert abs(float(a2["w"][0])) > abs(float(a1["w"][0]))
 
 

@@ -1,0 +1,158 @@
+"""Compile rehearsal for a TPU v5e chip, without the chip.
+
+The TPU compiler is installed next to the CPU backend, and compiles for a
+described (not attached) ``v5e:2x2`` topology.  These tests compile the
+main path at real sizes — the Pallas recurrent cells with
+``interpret=False``, the jitted federated round at the R1 cohort, the
+serving forward at its largest bucket — so a kernel or a program the chip
+would refuse fails here, at no chip time.  Nothing runs: a passing compile
+says nothing about results or speed.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may load the TPU library at a time,
+and every test worker imports this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.base import ForecasterConfig, TransformConfig
+from repro.core import fedavg, losses
+from repro.kernels import ops
+from repro.kernels.gru_cell import gru_cell
+from repro.kernels.lstm_cell import lstm_cell
+from repro.models import forecaster
+from repro.serving import engine as serving_engine
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+FCFG = ForecasterConfig()                       # the paper's LSTM, H = 64
+R1_M, R1_STEPS, R1_BATCH = 256, 410, 64         # R1 cohort, B = 64
+R1_WINDOWS = 26_280                             # a year of train windows
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no compiler logs
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off meanwhile
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fits_one_chip(compiled):
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert used < V5E_HBM_BYTES, f"{used / 2**30:.2f} GiB > 16 GiB"
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+@pytest.mark.parametrize("B,I", [(64, 1), (256, 1), (64, 64), (200, 1)])
+def test_recurrent_cell_compiles_for_v5e(one_chip, cell, B, I):
+    """Fused cell at H = 64 through ``ops._pick_block``'s tiling; B = 200
+    is the batch whose block used to be unaligned (100 rows)."""
+    H = FCFG.hidden_dim
+    gates = 4 if cell == "lstm" else 3
+    blocks = dict(block_b=ops._pick_block(B, ops._SUBLANE),
+                  block_h=ops._pick_block(H, ops._LANE), interpret=False)
+    f32 = jnp.float32
+    x, h = _spec((B, I), f32, one_chip), _spec((B, H), f32, one_chip)
+    w = (_spec((I, gates * H), f32, one_chip),
+         _spec((H, gates * H), f32, one_chip),
+         _spec((gates * H,), f32, one_chip))
+    if cell == "lstm":
+        fn = functools.partial(lstm_cell, **blocks)
+        args = (x, h, h) + w
+    else:
+        fn = functools.partial(gru_cell, **blocks)
+        args = (x, h) + w
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("cell", ["lstm", "gru"])
+def test_pallas_forecaster_paths_compile_for_v5e(one_chip, monkeypatch, cell):
+    """``cell_impl="pallas"`` through the differentiable wrapper: a vmapped
+    local update of 4 clients at B = 64 and the forecast at bucket 256.
+    Off-chip the platform picks interpret mode, so the test steers the
+    kernels to compile, and drops the traces it made afterwards."""
+    from repro.core import client
+    from repro.kernels import gru_cell as gru_mod, lstm_cell as lstm_mod
+
+    for mod in (lstm_mod, gru_mod):
+        monkeypatch.setattr(mod, "resolve_interpret", lambda i=None: False)
+    fcfg = ForecasterConfig(cell=cell)
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          forecaster.param_template(fcfg))
+    update = jax.jit(jax.vmap(functools.partial(
+        client.local_update, cfg=fcfg, loss=losses.make_loss("mse"),
+        cell_impl="pallas"), in_axes=(None, 0, 0, 0, None)))
+    try:
+        compiled = update.lower(
+            params, _spec((4, 512, fcfg.lookback, 1), f32, one_chip),
+            _spec((4, 512, fcfg.horizon), f32, one_chip),
+            _spec((4, 8, 64), jnp.int32, one_chip),
+            _spec((), f32, one_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        compiled = forecaster.forecast.lower(
+            params, _spec((256, fcfg.lookback, 1), f32, one_chip), cfg=fcfg,
+            cell_impl="pallas").compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    finally:
+        jax.clear_caches()
+
+
+def test_r1_round_compiles_for_v5e(one_chip):
+    """The jitted vmap round (local-update of 256 clients x 410 steps of
+    B = 64 on a year of windows, identity transform, aggregate) fits one
+    chip."""
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          forecaster.param_template(FCFG))
+    L, Hz = FCFG.lookback, FCFG.horizon
+    args = (params,
+            _spec((R1_M, R1_WINDOWS, L, 1), f32, one_chip),
+            _spec((R1_M, R1_WINDOWS, Hz), f32, one_chip),
+            _spec((R1_M, R1_STEPS, R1_BATCH), jnp.int32, one_chip),
+            _spec((R1_M,), f32, one_chip),
+            _spec((R1_M, 2), jnp.uint32, one_chip),
+            _spec((), f32, one_chip), _spec((), f32, one_chip))
+    compiled = fedavg.pipeline_round.lower(
+        *args, cfg=FCFG, loss=losses.make_loss("ew_mse", 2.0),
+        tcfg=TransformConfig(), cell_impl="jnp").compile()
+    _fits_one_chip(compiled)
+
+
+def test_serving_forward_compiles_for_v5e(one_chip):
+    """The engine's fp32 forward at its largest bucket (256 requests)."""
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          forecaster.param_template(FCFG))
+    b = 256
+    compiled = jax.jit(serving_engine._forecast_kwh,
+                       static_argnames=("cfg",)).lower(
+        params, _spec((b, FCFG.lookback), f32, one_chip),
+        _spec((b, 1), f32, one_chip), _spec((b, 1), f32, one_chip),
+        cfg=FCFG).compile()
+    _fits_one_chip(compiled)
