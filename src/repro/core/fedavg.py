@@ -255,9 +255,14 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     pair's masks sum to a multiple of ``2^b``) and decoded through the
     shared public grid scale: ``params + scale * wrap(sum of uploads)``.
     """
-    locals_, client_loss = jax.vmap(
-        local_update, in_axes=(None, 0, 0, 0, None, None, None, None, None))(
-        params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
+    # named scopes put the stage in each op's HLO ``op_name`` metadata
+    # (``.../local_update/...``), so a device trace attributes op time to
+    # its stage; they add no primitive
+    with jax.named_scope("local_update"):
+        locals_, client_loss = jax.vmap(
+            local_update,
+            in_axes=(None, 0, 0, 0, None, None, None, None, None))(
+            params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
     # taint source (production no-op): per-client local models — and the
     # deltas derived from them — are the private values flcheck tracks to
     # the aggregation boundary.  client_loss is deliberately NOT tagged:
@@ -265,16 +270,18 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     # documented in docs/privacy.md.
     locals_ = taint_mod.tag_private(locals_)
     stack = transforms_mod.make_stack(tcfg, scfg)
-    if stack.is_identity:
-        sums, wsum_local = _weighted_sums(locals_, weights)
-        wsum = agg.reduce(wsum_local)
-        w_agg = jax.tree.map(lambda s: agg.reduce(s) / wsum, sums)
-    else:
-        deltas = jax.tree.map(lambda l, g: l - g, locals_, params)
-        w_cohort = weights if w_full is None else w_full
-        deltas = apply_stack(stack, deltas, keys, slots=slots,
-                             w_full=w_cohort, round_key=round_key)
-        if stack.pre_weighted:
+    if not stack.is_identity:
+        with jax.named_scope("transform"):
+            deltas = jax.tree.map(lambda l, g: l - g, locals_, params)
+            w_cohort = weights if w_full is None else w_full
+            deltas = apply_stack(stack, deltas, keys, slots=slots,
+                                 w_full=w_cohort, round_key=round_key)
+    with jax.named_scope("aggregate"):
+        if stack.is_identity:
+            sums, wsum_local = _weighted_sums(locals_, weights)
+            wsum = agg.reduce(wsum_local)
+            w_agg = jax.tree.map(lambda s: agg.reduce(s) / wsum, sums)
+        elif stack.pre_weighted:
             # uploads already carry their weight share — sum UNWEIGHTED
             sums = jax.tree.map(lambda d: jnp.sum(d, axis=0), deltas)
             wsum = agg.reduce(jnp.sum(weights))
@@ -296,7 +303,7 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
             wsum = agg.reduce(wsum_local)
             w_agg = jax.tree.map(lambda g, s: g + agg.reduce(s) / wsum,
                                  params, sums)
-    loss_mean = agg.reduce(jnp.sum(weights * client_loss)) / wsum
+        loss_mean = agg.reduce(jnp.sum(weights * client_loss)) / wsum
     return w_agg, loss_mean
 
 
@@ -937,42 +944,59 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
         m_run = -(-m_sel // n_dev) * n_dev
         stopped = False
         for t in range(t0, flcfg.rounds):
-            # membership churn: absent members sit this round out (pure
-            # function of (seed, round, client id) — replayable).  If the
-            # whole cluster is absent, fall back to full membership rather
-            # than dispatch nothing.  Shapes stay fixed at m_run: a smaller
-            # selection just grows the zero-weight padding.
-            avail = members
-            if engine.latency.churn.absent_prob > 0.0:
-                mask = engine.latency.available(t, members)
-                if mask.any():
-                    avail = members[mask]
-            sel = engine.select(rng, avail, min(m_sel, len(avail)), t,
-                                counts[avail])
-            bidx = partition.ragged_minibatch_indices(
-                rng, counts[sel], steps, ccfg.batch_size)
-            pad_idx = np.resize(np.arange(len(sel)), m_run)
-            x, y, c_sel = provider.round_batch(sel[pad_idx])
-            w = c_sel.copy()
-            w[len(sel):] = 0.0                        # mask padding clients
-            params, sstate, l = engine.step(
-                params, sstate, *engine.put_clients(x, y, bidx[pad_idx]), w,
-                round_idx=t, stream=cid if cid >= 0 else 0)
-            hist.append(float(l))
-            sim_hist.append(engine.sim_time)
-            eps_hist.append(engine.accountant.epsilon())
-            if log_every and (t + 1) % log_every == 0:
-                eps = eps_hist[-1]
-                eps_s = f" eps {eps:.2f}" if np.isfinite(eps) else ""
-                print(f"[cluster {cid}] round {t+1}/{flcfg.rounds} "
-                      f"loss {hist[-1]:.5f} sim_t {sim_hist[-1]:.1f}s{eps_s}")
-            executed += 1
-            stopped = (stop_after_rounds is not None
-                       and executed >= stop_after_rounds)
-            if checkpoint_path is not None and (
-                    (t + 1) % max(checkpoint_every, 1) == 0
-                    or t + 1 == flcfg.rounds or stopped):
-                _save(cid, params, sstate, hist, sim_hist, eps_hist, t + 1)
+            # host spans on the profiler's clock (about a microsecond each
+            # when no trace is active): a device trace attributes each idle
+            # gap of the chip to the host stage that left it idle
+            with jax.profiler.StepTraceAnnotation("fl.round", step_num=t):
+                with jax.profiler.TraceAnnotation("fl.select"):
+                    # membership churn: absent members sit this round out
+                    # (pure function of (seed, round, client id) —
+                    # replayable).  If the whole cluster is absent, fall
+                    # back to full membership rather than dispatch nothing.
+                    # Shapes stay fixed at m_run: a smaller selection just
+                    # grows the zero-weight padding.
+                    avail = members
+                    if engine.latency.churn.absent_prob > 0.0:
+                        mask = engine.latency.available(t, members)
+                        if mask.any():
+                            avail = members[mask]
+                    sel = engine.select(rng, avail, min(m_sel, len(avail)),
+                                        t, counts[avail])
+                    bidx = partition.ragged_minibatch_indices(
+                        rng, counts[sel], steps, ccfg.batch_size)
+                    pad_idx = np.resize(np.arange(len(sel)), m_run)
+                with jax.profiler.TraceAnnotation("fl.round_batch",
+                                                  clients=m_run) as span:
+                    x, y, c_sel = provider.round_batch(sel[pad_idx])
+                    span.set_metadata(windows=int(c_sel.sum()))
+                w = c_sel.copy()
+                w[len(sel):] = 0.0                    # mask padding clients
+                with jax.profiler.TraceAnnotation("fl.put") as span:
+                    put = engine.put_clients(x, y, bidx[pad_idx])
+                    span.set_metadata(bytes=sum(a.nbytes for a in put))
+                with jax.profiler.TraceAnnotation("fl.step"):
+                    params, sstate, l = engine.step(
+                        params, sstate, *put, w, round_idx=t,
+                        stream=cid if cid >= 0 else 0)
+                del put          # the device inputs die with their round
+                with jax.profiler.TraceAnnotation("fl.loss_sync"):
+                    hist.append(float(l))
+                sim_hist.append(engine.sim_time)
+                eps_hist.append(engine.accountant.epsilon())
+                if log_every and (t + 1) % log_every == 0:
+                    eps = eps_hist[-1]
+                    eps_s = f" eps {eps:.2f}" if np.isfinite(eps) else ""
+                    print(f"[cluster {cid}] round {t+1}/{flcfg.rounds} "
+                          f"loss {hist[-1]:.5f} sim_t {sim_hist[-1]:.1f}s"
+                          f"{eps_s}")
+                executed += 1
+                stopped = (stop_after_rounds is not None
+                           and executed >= stop_after_rounds)
+                if checkpoint_path is not None and (
+                        (t + 1) % max(checkpoint_every, 1) == 0
+                        or t + 1 == flcfg.rounds or stopped):
+                    _save(cid, params, sstate, hist, sim_hist, eps_hist,
+                          t + 1)
             if stopped:
                 break
         results[cid] = FLResult(params, np.array(hist),

@@ -126,7 +126,7 @@ def test_pallas_forecaster_paths_compile_for_v5e(one_chip, monkeypatch, cell):
 def test_r1_round_compiles_for_v5e(one_chip):
     """The jitted vmap round (local-update of 256 clients x 410 steps of
     B = 64 on a year of windows, identity transform, aggregate) fits one
-    chip."""
+    chip, and its stage scopes reach the compiled ops' metadata."""
     f32 = jnp.float32
     params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
                           forecaster.param_template(FCFG))
@@ -142,6 +142,9 @@ def test_r1_round_compiles_for_v5e(one_chip):
         *args, cfg=FCFG, loss=losses.make_loss("ew_mse", 2.0),
         tcfg=TransformConfig(), cell_impl="jnp").compile()
     _fits_one_chip(compiled)
+    # the stage scopes survive the TPU compiler, where the trace reads them
+    text = compiled.as_text()
+    assert "/local_update/" in text and "/aggregate/" in text
 
 
 def test_serving_forward_compiles_for_v5e(one_chip):
