@@ -41,20 +41,47 @@ def sgd_step(params, batch, lr, cfg: ForecasterConfig, loss: Callable,
     return params, l
 
 
+def minibatches(x, y, cfg: ForecasterConfig) -> Callable:
+    """``take(idx)``: the windows ``idx`` (B,) of one client's data as
+    ``{"x": (B, L, 1), "y": (B, H)}``, from either form of the data.
+
+    Window form: x (n_win, L, 1) and y (n_win, H), row-gathered.  Series
+    form (``y is None``): x is the client's (T,) normalized series, window
+    k its ``L + H`` values from k.  The series is windowed here, once per
+    round and outside the step loop, into an (n_win, L + H) tensor that
+    each step row-gathers: a TPU gathers whole rows natively, but lowers a
+    gather of unaligned ``L + H``-wide slices of the series itself to a
+    serial loop over the slices, and one of single elements runs slower
+    than the row gather too.
+    """
+    if y is not None:
+        return lambda idx: {"x": x[idx], "y": y[idx]}
+    lb, width = cfg.lookback, cfg.lookback + cfg.horizon
+    n = x.shape[0] - width + 1
+    win = jnp.stack([x[q:q + n] for q in range(width)], axis=-1)
+
+    def take(idx):
+        w = win[idx]
+        return {"x": w[:, :lb, None], "y": w[:, lb:]}
+    return take
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
 def local_update(params, x, y, batch_idx, lr, cfg: ForecasterConfig,
                  loss: Callable, cell_impl: str = "jnp", prox_mu=0.0):
     """Run the client's local schedule.
 
-    params: global model (pytree); x: (n_win, L, 1); y: (n_win, H);
+    params: global model (pytree); x: (n_win, L, 1) and y: (n_win, H), or
+    x: (T,) normalized series and y None (see :func:`minibatches`);
     batch_idx: (steps, B) int32; prox_mu: FedProx strength (0 = plain FedAvg).
     Returns (local params, mean local loss).
     """
     anchor = params                      # round-start global model (FedProx)
+    take = minibatches(x, y, cfg)
 
     def step(p, idx):
-        return sgd_step(p, {"x": x[idx], "y": y[idx]}, lr, cfg, loss,
-                        cell_impl, anchor=anchor, prox_mu=prox_mu)
+        return sgd_step(p, take(idx), lr, cfg, loss, cell_impl,
+                        anchor=anchor, prox_mu=prox_mu)
 
     params, losses = jax.lax.scan(step, params, batch_idx)
     return params, jnp.mean(losses)

@@ -602,12 +602,14 @@ class RoundEngine:
              round_idx: int = 0, stream: int = 0):
         """One full round on already-selected client data.
 
-        x: (M, n_win, L, 1); y: (M, n_win, H); batch_idx: (M, steps, B);
-        weights: (M,) per-client sample counts — zero marks mesh-padding
-        duplicates, which are excluded from aggregation AND loss on both the
-        uniform and weighted paths.  ``round_idx`` / ``stream`` seed the
-        per-client transform keys (only consumed when a transform stack is
-        configured).  Returns ``(new params, new server state, round loss)``.
+        x: (M, n_win, L, 1) and y: (M, n_win, H) windows, or x: (M, T)
+        normalized series and y None (``client.minibatches``); batch_idx:
+        (M, steps, B); weights: (M,) per-client sample counts — zero marks
+        mesh-padding duplicates, which are excluded from aggregation AND
+        loss on both the uniform and weighted paths.  ``round_idx`` /
+        ``stream`` seed the per-client transform keys (only consumed when a
+        transform stack is configured).  Returns ``(new params, new server
+        state, round loss)``.
 
         Dispatches on ``FLConfig.mode``: ``sync`` (default) waits for every
         client — the round's simulated cost is the slowest client's latency;
@@ -720,8 +722,8 @@ def _seed_rngs(seed: int):
 def _as_provider(data, fcfg: ForecasterConfig) -> windows.ClientWindowProvider:
     if isinstance(data, windows.ClientWindowProvider):
         return data
-    # in-memory sources window each client at most once: the raw series are
-    # already resident, so caching all N clients costs no more than the old
+    # in-memory sources cache every client: the raw series are already
+    # resident, so caching all N clients costs no more than the old
     # materialize-everything path did, and full-participation configs
     # (clients_per_round == N) would thrash any smaller LRU every round
     return windows.ClientWindowProvider.from_series(
@@ -768,9 +770,10 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
 
     all_series: (N, T) raw kWh (one row per client), a ragged list of (T_i,)
     series, or a ``windows.ClientWindowProvider`` — everything is routed
-    through the provider, so each round fetches/normalizes/windows ONLY the
-    ``m`` selected clients (host→device traffic O(m), never O(N)).  When
-    ``flcfg.holdout_frac > 0`` that fraction of clients is excluded from
+    through the provider, so each round fetches and normalizes ONLY the
+    ``m`` selected clients (host→device traffic O(m), never O(N)) and ships
+    their train series, which the device windows (``client.minibatches``).
+    When ``flcfg.holdout_frac > 0`` that fraction of clients is excluded from
     training entirely (unseen-client generalization split; their indices are
     reported on every ``FLResult.heldout_clients``).  Returns
     {cluster_id: FLResult}; cluster_id = -1 when clustering is off.
@@ -965,18 +968,23 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
                     bidx = partition.ragged_minibatch_indices(
                         rng, counts[sel], steps, ccfg.batch_size)
                     pad_idx = np.resize(np.arange(len(sel)), m_run)
-                with jax.profiler.TraceAnnotation("fl.round_batch",
-                                                  clients=m_run) as span:
-                    x, y, c_sel = provider.round_batch(sel[pad_idx])
-                    span.set_metadata(windows=int(c_sel.sum()))
+                # the round ships the cohort's normalized train series,
+                # L + H times fewer bytes than its windows; the device
+                # windows them (client.minibatches)
+                with jax.profiler.TraceAnnotation(
+                        "fl.round_batch", clients=m_run,
+                        layout="series") as span:
+                    s, c_sel = provider.round_series(sel[pad_idx])
+                    span.set_metadata(windows=int(c_sel.sum()),
+                                      bytes=s.nbytes)
                 w = c_sel.copy()
                 w[len(sel):] = 0.0                    # mask padding clients
                 with jax.profiler.TraceAnnotation("fl.put") as span:
-                    put = engine.put_clients(x, y, bidx[pad_idx])
+                    put = engine.put_clients(s, bidx[pad_idx])
                     span.set_metadata(bytes=sum(a.nbytes for a in put))
                 with jax.profiler.TraceAnnotation("fl.step"):
                     params, sstate, l = engine.step(
-                        params, sstate, *put, w, round_idx=t,
+                        params, sstate, put[0], None, put[1], w, round_idx=t,
                         stream=cid if cid >= 0 else 0)
                 del put          # the device inputs die with their round
                 with jax.profiler.TraceAnnotation("fl.loss_sync"):

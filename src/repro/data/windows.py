@@ -4,7 +4,7 @@ Per building: Min–Max scale to [0,1] over the entire year, frame into
 look-back-8 / horizon-4 windows, split 75:25 chronologically (≈9 months train,
 3 months test).
 
-Two data paths share this math:
+Three data paths share this math:
 
 * :func:`batched_client_windows` materializes the full ``(N, n_win, L, 1)``
   train/test tensors — fine for dozens of clients, quadratic pain at 10k+.
@@ -15,6 +15,12 @@ Two data paths share this math:
   zero-padded to a fixed ``(m, n_win_max, L, 1)`` shape and carries per-client
   valid-window counts; training draws minibatch indices in ``[0, count_i)``
   so the padding is never read.
+* :meth:`ClientWindowProvider.round_series` is what the round loop ships:
+  the cohort's normalized TRAIN series, zero-padded to ``(m, cut_max)``,
+  and no windows at all.  Window ``k`` of client ``j`` is the ``L + H``
+  consecutive values from ``s[j, k]``; the device windows the series
+  (``core/client.py::minibatches``), so the host builds and copies
+  ``L + H`` times fewer bytes than the window batch.
 """
 from __future__ import annotations
 
@@ -246,6 +252,26 @@ class ClientWindowProvider:
         counts = self.train_counts[np.asarray(ids)]
         x, y = self._stack(ids, "x_train", "y_train", counts, self.n_win_max)
         return x, y, counts.astype(np.float32)
+
+    def round_series(self, ids) -> Tuple[np.ndarray, np.ndarray]:
+        """Normalized train series for the clients selected THIS round.
+
+        Returns ``(s, counts)`` with s: (m, cut_max) float32, row j client
+        j's series min–max normalized over its whole history (in the
+        series' own dtype, as :meth:`_client` does) cut to its train part
+        and zero-padded to the longest cut; counts: (m,) float32 valid
+        train-window counts, as :meth:`round_batch` returns them.  Window
+        k < count_j is ``s[j, k:k+L]`` / ``s[j, k+L:k+L+H]``, bit-for-bit
+        ``round_batch``'s; it ends at or before the client's cut, so the
+        padding is never read.
+        """
+        ids = np.asarray(ids)
+        s = np.zeros((len(ids), int(self._cuts.max())), np.float32)
+        for j, i in enumerate(ids):
+            norm, _ = minmax_normalize(self._series(int(i)))
+            cut = self._cuts[i]
+            s[j, :cut] = norm[:cut]
+        return s, self.train_counts[ids].astype(np.float32)
 
     def test_batch(self, ids):
         """Test windows + per-client (lo, hi) stats, same padding scheme."""

@@ -48,6 +48,31 @@ def test_round_batch_bit_identical_to_materialized(equal_series):
     np.testing.assert_array_equal(hi, data["stats"][1][ids])
 
 
+@pytest.mark.parametrize("fleet", ["equal_series", "ragged_series"])
+def test_round_series_windows_are_round_batch_windows(fleet, request):
+    """Window k of client j sliced out of ``round_series`` is bit-for-bit
+    ``round_batch``'s, for every valid k; rows past a client's cut are
+    zero; the series is m * cut_max float32, L + H times under the
+    windows."""
+    prov = ClientWindowProvider.from_series(request.getfixturevalue(fleet),
+                                            FCFG.lookback, FCFG.horizon)
+    ids = [4, 0, 5, 1]
+    x, y, counts = prov.round_batch(ids)
+    s, s_counts = prov.round_series(ids)
+    L, H = FCFG.lookback, FCFG.horizon
+    cut_max = int(prov.n_win_max) + L + H - 1
+    assert s.dtype == np.float32 and s.shape == (len(ids), cut_max)
+    assert s.nbytes == len(ids) * cut_max * 4
+    np.testing.assert_array_equal(s_counts, counts)
+    k = np.arange(prov.n_win_max)
+    for j, c in enumerate(counts.astype(int)):
+        np.testing.assert_array_equal(
+            s[j][k[:c, None] + np.arange(L)], x[j, :c, :, 0])
+        np.testing.assert_array_equal(
+            s[j][k[:c, None] + L + np.arange(H)], y[j, :c])
+        assert (s[j, c + L + H - 1:] == 0).all()
+
+
 def test_synthetic_provider_matches_in_memory(equal_series):
     """On-demand generator variant == wrapping the pre-generated array."""
     p_mem = ClientWindowProvider.from_series(equal_series, FCFG.lookback,

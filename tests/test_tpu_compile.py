@@ -147,6 +147,35 @@ def test_r1_round_compiles_for_v5e(one_chip):
     assert "/local_update/" in text and "/aggregate/" in text
 
 
+def test_r1_series_round_compiles_for_v5e(one_chip):
+    """The round as the training loop ships it: the cohort's normalized
+    train series (y None) and the minibatch indices.  It fits one chip
+    with a sixth of the window form's arguments, and the device windowing
+    adds no loop to the program: no gather of the series lowers to a
+    serial loop over its slices."""
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          forecaster.param_template(FCFG))
+    L, Hz = FCFG.lookback, FCFG.horizon
+    kw = dict(cfg=FCFG, loss=losses.make_loss("ew_mse", 2.0),
+              tcfg=TransformConfig(), cell_impl="jnp")
+    rest = (_spec((R1_M, R1_STEPS, R1_BATCH), jnp.int32, one_chip),
+            _spec((R1_M,), f32, one_chip),
+            _spec((R1_M, 2), jnp.uint32, one_chip),
+            _spec((), f32, one_chip), _spec((), f32, one_chip))
+    series = fedavg.pipeline_round.lower(
+        params, _spec((R1_M, R1_WINDOWS + L + Hz - 1), f32, one_chip), None,
+        *rest, **kw).compile()
+    windows = fedavg.pipeline_round.lower(
+        params, _spec((R1_M, R1_WINDOWS, L, 1), f32, one_chip),
+        _spec((R1_M, R1_WINDOWS, Hz), f32, one_chip), *rest, **kw).compile()
+    _fits_one_chip(series)
+    s_mem, w_mem = series.memory_analysis(), windows.memory_analysis()
+    assert 6 * s_mem.argument_size_in_bytes < w_mem.argument_size_in_bytes
+    assert series.as_text().count(" while(") == \
+        windows.as_text().count(" while(")
+
+
 def test_serving_forward_compiles_for_v5e(one_chip):
     """The engine's fp32 forward at its largest bucket (256 requests)."""
     f32 = jnp.float32

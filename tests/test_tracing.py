@@ -63,20 +63,26 @@ def test_one_round_span_per_round_each_holding_every_stage_once(traced):
 
 
 def test_put_span_counts_the_bytes_put(traced, series):
-    """``fl.put``'s ``bytes`` is what lands on the device: float32 windows
-    and targets, and the minibatch indices after JAX's int64 -> int32."""
+    """``fl.put``'s ``bytes`` is what lands on the device: the cohort's
+    float32 normalized train series and the minibatch indices after JAX's
+    int64 -> int32; ``fl.round_batch`` says it built series and how many
+    bytes of them."""
     _, _, spans = traced
     prov = windows.ClientWindowProvider.from_series(
         series, FCFG.lookback, FCFG.horizon)
     m, n_win = FLCFG.clients_per_round, int(prov.n_win_max)
     steps = partition.local_steps(prov.n_win_max, FLCFG.batch_size,
                                   FLCFG.local_epochs)
-    want = 4 * (m * n_win * FCFG.lookback * FCFG.input_dim
-                + m * n_win * FCFG.horizon + m * steps * FLCFG.batch_size)
+    cut_max = n_win + FCFG.lookback + FCFG.horizon - 1
+    series_bytes = 4 * m * cut_max
+    want = series_bytes + 4 * m * steps * FLCFG.batch_size
     puts = [s[3] for s in spans if s[2] == "fl.put"]
     assert [p["bytes"] for p in puts] == [want] * FLCFG.rounds
     batches = [s[3] for s in spans if s[2] == "fl.round_batch"]
-    assert all(b["clients"] == m and b["windows"] > 0 for b in batches)
+    assert len(batches) == FLCFG.rounds
+    assert all(b["clients"] == m and b["windows"] > 0
+               and b["layout"] == "series" and b["bytes"] == series_bytes
+               for b in batches)
 
 
 def test_loss_history_bit_identical_under_an_active_trace(traced):
