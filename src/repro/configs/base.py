@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Protocol, Tuple
 
 
 @dataclass(frozen=True)
@@ -211,6 +211,39 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
 
+class ModelSpec(Protocol):
+    """What the federated round engine needs of a model.
+
+    ``core/client.py`` (``sgd_step``, ``local_update``), ``core/fedavg.py``
+    (``pipeline_round``, ``RoundEngine``, ``run_federated_training``) and
+    the serving registry's checkpoint template take a spec and nothing
+    model-specific.  A
+    spec is a frozen dataclass, so the jitted round is keyed on it.  A
+    client's data is windows of ``lookback + horizon`` consecutive readings
+    of its series; ``batch`` turns a (B, lookback + horizon) block of them
+    into the model's batch.  The paper's LSTM/GRU
+    (:class:`ForecasterConfig`) and the hybrid Mamba2/attention backbone
+    (:class:`HybridForecasterConfig`) are the two specs.
+    """
+    lookback: int
+    horizon: int
+
+    def init(self, key) -> Any:
+        """Initial parameters from a PRNG key."""
+
+    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+        """Scalar training loss of one batch."""
+
+    def param_template(self) -> Any:
+        """Zero tree with :meth:`init`'s structure, shapes and dtypes."""
+
+    def batch(self, windows) -> Dict[str, Any]:
+        """(B, lookback + horizon) windows -> the model's batch."""
+
+    def num_params(self) -> int:
+        ...
+
+
 @dataclass(frozen=True)
 class ForecasterConfig:
     """The paper's RNN demand-forecasting model (§3.2)."""
@@ -230,6 +263,100 @@ class ForecasterConfig:
             n += gates * h * (inp + h + 1)
         n += h * self.horizon + self.horizon
         return n
+
+    # ---------------------------------------------- ModelSpec (models/)
+    def init(self, key):
+        from repro.models import forecaster
+        return forecaster.init_forecaster(key, self)
+
+    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+        from repro.models import forecaster
+        return forecaster.loss_fn(params, batch, self, loss, cell_impl)
+
+    def param_template(self):
+        from repro.models import forecaster
+        return forecaster.param_template(self)
+
+    def batch(self, windows):
+        """x: the look-back (B, L, 1); y: the horizon (B, H)."""
+        return {"x": windows[:, :self.lookback, None],
+                "y": windows[:, self.lookback:]}
+
+
+@dataclass(frozen=True)
+class HybridForecasterConfig:
+    """A decoder-only load forecaster on a hybrid Mamba2/attention stack
+    (``models/hybrid_forecaster.py``): each reading is one position, and
+    every position forecasts the next ``horizon`` readings.
+
+    The block follows granite-4.0-h (IBM, ``granitemoehybrid``): per layer
+    a Mamba2 or a NoPE GQA mixer and a gated dense MLP, each behind an
+    RMSNorm and scaled by ``residual_multiplier`` into the residual;
+    ``embedding_multiplier`` scales the input, ``logits_scaling`` divides
+    the output, ``attention_multiplier`` replaces 1/sqrt(head_dim).  The
+    defaults are the published widths, cut to ``layer_types`` (one period
+    of the 9:1 Mamba2:attention pattern).
+    """
+    layer_types: Tuple[str, ...] = ("mamba",) * 5 + ("attention",) + \
+        ("mamba",) * 4
+    d_model: int = 2048
+    n_heads: int = 32
+    n_kv_heads: int = 8
+    head_dim: int = 64
+    d_ff: int = 8192
+    ssm: SSMConfig = SSMConfig(state_dim=128, head_dim=64, expand=2,
+                               conv_width=4, chunk_size=256, n_groups=1)
+    norm_eps: float = 1e-5
+    attention_multiplier: float = 0.015625
+    residual_multiplier: float = 0.22
+    embedding_multiplier: float = 12.0
+    logits_scaling: float = 8.0
+    lookback: int = 2048               # positions per window
+    horizon: int = 4                   # readings forecast at each position
+
+    def __post_init__(self):
+        bad = set(self.layer_types) - {"mamba", "attention"}
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(bad)}")
+
+    @property
+    def backbone(self) -> ModelConfig:
+        """The widths as ``models/ssm.py`` and ``models/attention.py``
+        read them."""
+        return ModelConfig(
+            name="hybrid_forecaster", arch_type="hybrid",
+            n_layers=len(self.layer_types), d_model=self.d_model,
+            n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
+            d_ff=self.d_ff, vocab_size=0, head_dim=self.head_dim,
+            norm_eps=self.norm_eps, ssm=self.ssm)
+
+    def num_params(self) -> int:
+        import jax
+        from repro.models import hybrid_forecaster
+        return sum(a.size for a in jax.tree.leaves(
+            hybrid_forecaster.param_shapes(self)))
+
+    # ---------------------------------------------- ModelSpec (models/)
+    def init(self, key):
+        from repro.models import hybrid_forecaster
+        return hybrid_forecaster.init(key, self)
+
+    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+        from repro.models import hybrid_forecaster
+        return hybrid_forecaster.loss_fn(params, batch, self, loss)
+
+    def param_template(self):
+        from repro.models import hybrid_forecaster
+        return hybrid_forecaster.param_template(self)
+
+    def batch(self, windows):
+        """x: the first ``lookback`` readings (B, L); y: at each position
+        t the ``horizon`` readings after it, (B, L, H)."""
+        import jax.numpy as jnp
+        L = self.lookback
+        return {"x": windows[:, :L],
+                "y": jnp.stack([windows[:, 1 + h:1 + h + L]
+                                for h in range(self.horizon)], axis=-1)}
 
 
 # ---------------------------------------------------------------------------
@@ -275,16 +402,21 @@ class SamplingConfig:
 
 @dataclass(frozen=True)
 class ClientOptConfig:
-    """Local-update stage: E epochs of minibatch SGD (``core/client.py``)."""
+    """Local-update stage: E epochs of minibatch SGD (``core/client.py``),
+    or K steps where ``local_steps`` is set (cross-silo FedAvg, each step
+    B windows drawn uniformly from the client's)."""
     lr: float = 1e-2
     local_epochs: int = 1              # E
     batch_size: int = 64               # B
+    local_steps: int = 0               # K (0 = E epochs)
     loss: str = "ew_mse"               # "mse" | "ew_mse"
     beta: float = 2.0                  # EW-MSE beta (>1)
     prox_mu: float = 0.0               # FedProx proximal strength
 
     def __post_init__(self):
         _check_choice("loss", self.loss, LOSSES)
+        if self.local_steps < 0:
+            raise ValueError(f"local_steps={self.local_steps} < 0")
 
 
 @dataclass(frozen=True)
@@ -588,6 +720,7 @@ class FLConfig:
     clients_per_round: int = 100       # M
     local_epochs: int = 1              # E
     batch_size: int = 64               # B
+    local_steps: int = 0               # K SGD steps a round (0 = E epochs)
     rounds: int = 500                  # T
     lr: float = 1e-2
     loss: str = "ew_mse"               # "mse" | "ew_mse"
@@ -663,7 +796,8 @@ class FLConfig:
     @property
     def client_opt(self) -> ClientOptConfig:
         return ClientOptConfig(lr=self.lr, local_epochs=self.local_epochs,
-                               batch_size=self.batch_size, loss=self.loss,
+                               batch_size=self.batch_size,
+                               local_steps=self.local_steps, loss=self.loss,
                                beta=self.beta, prox_mu=self.prox_mu)
 
     @property

@@ -59,7 +59,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import checkpoint as checkpoint_mod
 from repro.analysis import taint as taint_mod
 from repro.configs.base import (AggregationConfig, FLConfig, ForecasterConfig,
-                                SecureAggConfig, TransformConfig)
+                                ModelSpec, SecureAggConfig, TransformConfig)
 from repro.core import aggregation as aggregation_mod
 from repro.core import clustering, losses as losses_mod
 from repro.core import privacy as privacy_mod
@@ -102,7 +102,7 @@ def weighted_aggregate(stacked_params, weights):
 
 # ------------------------------------------------------------ vmap execution
 @functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
-def fedavg_round(params, x, y, batch_idx, lr, cfg: ForecasterConfig,
+def fedavg_round(params, x, y, batch_idx, lr, cfg: ModelSpec,
                  loss: Callable, cell_impl: str = "jnp"):
     """One uniform-FedAvg round over M clients (pseudo-distributed, back-compat).
 
@@ -116,7 +116,7 @@ def fedavg_round(params, x, y, batch_idx, lr, cfg: ForecasterConfig,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
 def engine_round(params, x, y, batch_idx, weights, lr, prox_mu,
-                 cfg: ForecasterConfig, loss: Callable,
+                 cfg: ModelSpec, loss: Callable,
                  cell_impl: str = "jnp"):
     """Generalized round: weighted aggregation + optional FedProx clients.
 
@@ -134,7 +134,7 @@ def engine_round(params, x, y, batch_idx, weights, lr, prox_mu,
 
 
 # ------------------------------------------------------- shard_map execution
-def make_sharded_round(mesh, cfg: ForecasterConfig, loss: Callable,
+def make_sharded_round(mesh, cfg: ModelSpec, loss: Callable,
                        client_axis: str = "clients", cell_impl: str = "jnp"):
     """Uniform-FedAvg round with clients sharded over a mesh axis (back-compat).
 
@@ -161,7 +161,7 @@ def make_sharded_round(mesh, cfg: ForecasterConfig, loss: Callable,
 
 
 @functools.lru_cache(maxsize=None)
-def make_sharded_engine_round(mesh, cfg: ForecasterConfig, loss: Callable,
+def make_sharded_engine_round(mesh, cfg: ModelSpec, loss: Callable,
                               client_axis: str = "clients",
                               cell_impl: str = "jnp"):
     """Generalized sharded round; aggregation stays ONE psum of the param tree.
@@ -227,7 +227,7 @@ def apply_stack(stack, deltas, keys, *, slots=None, w_full=None,
 
 
 def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
-                   cfg: ForecasterConfig, loss: Callable, cell_impl: str,
+                   cfg: ModelSpec, loss: Callable, cell_impl: str,
                    tcfg: TransformConfig, agg: "aggregation_mod.Aggregator",
                    scfg: Optional[SecureAggConfig] = None, round_key=None,
                    slots=None, w_full=None):
@@ -255,6 +255,13 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     pair's masks sum to a multiple of ``2^b``) and decoded through the
     shared public grid scale: ``params + scale * wrap(sum of uploads)``.
     """
+    stack = transforms_mod.make_stack(tcfg, scfg)
+    w_cohort = weights if w_full is None else w_full
+    if client_loop(params, x.shape[0]) == "scan":
+        return _scan_round(params, x, y, batch_idx, weights, keys, lr,
+                           prox_mu, cfg=cfg, loss=loss, cell_impl=cell_impl,
+                           stack=stack, agg=agg, slots=slots,
+                           w_cohort=w_cohort, round_key=round_key)
     # named scopes put the stage in each op's HLO ``op_name`` metadata
     # (``.../local_update/...``), so a device trace attributes op time to
     # its stage; they add no primitive
@@ -269,11 +276,9 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     # the weighted scalar loss release is the accepted disclosure
     # documented in docs/privacy.md.
     locals_ = taint_mod.tag_private(locals_)
-    stack = transforms_mod.make_stack(tcfg, scfg)
     if not stack.is_identity:
         with jax.named_scope("transform"):
             deltas = jax.tree.map(lambda l, g: l - g, locals_, params)
-            w_cohort = weights if w_full is None else w_full
             deltas = apply_stack(stack, deltas, keys, slots=slots,
                                  w_full=w_cohort, round_key=round_key)
     with jax.named_scope("aggregate"):
@@ -281,37 +286,124 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
             sums, wsum_local = _weighted_sums(locals_, weights)
             wsum = agg.reduce(wsum_local)
             w_agg = jax.tree.map(lambda s: agg.reduce(s) / wsum, sums)
-        elif stack.pre_weighted:
-            # uploads already carry their weight share — sum UNWEIGHTED
-            sums = jax.tree.map(lambda d: jnp.sum(d, axis=0), deltas)
-            wsum = agg.reduce(jnp.sum(weights))
-            ring = stack.ring_spec
-            if ring is not None:
-                bits, sensitivity, headroom = ring
-                scale = transforms_mod.ring_scale(bits, sensitivity,
-                                                  w_cohort.shape[0],
-                                                  headroom)
-                w_agg = jax.tree.map(
-                    lambda g, s: g + scale * transforms_mod.ring_wrap(
-                        agg.reduce(s), bits),
-                    params, sums)
-            else:
-                w_agg = jax.tree.map(lambda g, s: g + agg.reduce(s) / wsum,
-                                     params, sums)
         else:
-            sums, wsum_local = _weighted_sums(deltas, weights)
-            wsum = agg.reduce(wsum_local)
-            w_agg = jax.tree.map(lambda g, s: g + agg.reduce(s) / wsum,
-                                 params, sums)
+            if stack.pre_weighted:
+                # uploads already carry their weight share — sum UNWEIGHTED
+                sums = jax.tree.map(lambda d: jnp.sum(d, axis=0), deltas)
+            else:
+                sums, _ = _weighted_sums(deltas, weights)
+            wsum = agg.reduce(jnp.sum(weights))
+            w_agg = _aggregate_deltas(params, sums, wsum, stack, agg,
+                                      w_cohort)
         loss_mean = agg.reduce(jnp.sum(weights * client_loss)) / wsum
     return w_agg, loss_mean
+
+
+def _aggregate_deltas(params, sums, wsum, stack, agg, w_cohort):
+    """The new global model from the cohort's summed transformed deltas:
+    ``params + sum / W``, or, for the ring quantizer, the reduced sum
+    wrapped back into the centered ring (exact — each pair's masks sum to
+    a multiple of ``2^b``) and decoded through the shared public grid
+    scale, ``params + scale * wrap(sum)``."""
+    ring = stack.ring_spec if stack.pre_weighted else None
+    if ring is None:
+        return jax.tree.map(lambda g, s: g + agg.reduce(s) / wsum, params,
+                            sums)
+    bits, sensitivity, headroom = ring
+    scale = transforms_mod.ring_scale(bits, sensitivity, w_cohort.shape[0],
+                                      headroom)
+    return jax.tree.map(
+        lambda g, s: g + scale * transforms_mod.ring_wrap(agg.reduce(s),
+                                                          bits),
+        params, sums)
+
+
+def _scan_round(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
+                cfg: ModelSpec, loss: Callable, cell_impl: str, stack, agg,
+                slots, w_cohort, round_key):
+    """The round with its clients one after another (:func:`client_loop`).
+
+    Each client adds its term to the one tree they share: under the
+    identity stack ``(w_i / W) * local_i``, so the shared tree ends as the
+    aggregate itself (W the cohort's weight, known before the first
+    client) and the round holds four parameter trees, not five: the
+    global model, the aggregate, one client's copy and its gradient.  A
+    transform stack's deltas are summed as the vmap path sums them and
+    decoded by :func:`_aggregate_deltas`.
+    """
+    if slots is None:
+        slots = jnp.arange(x.shape[0])
+    one_client = lambda t: jax.tree.map(lambda a: a[None], t)
+    with jax.named_scope("aggregate"):
+        wsum = agg.reduce(jnp.sum(weights))
+
+    def client(acc, inp):
+        xi, yi, bi, wi, ki, si = inp
+        with jax.named_scope("local_update"):
+            local, l = local_update(params, xi, yi, bi, lr, cfg, loss,
+                                    cell_impl, prox_mu)
+        local = taint_mod.tag_private(local)       # as on the vmap path
+        if stack.is_identity:
+            term = jax.tree.map(lambda a: a * (wi / wsum), local)
+        else:
+            with jax.named_scope("transform"):
+                delta = jax.tree.map(lambda a, g: a - g, local, params)
+                delta = jax.tree.map(lambda a: a[0], apply_stack(
+                    stack, one_client(delta), ki[None], slots=si[None],
+                    w_full=w_cohort, round_key=round_key))
+            term = (delta if stack.pre_weighted
+                    else jax.tree.map(lambda a: a * wi, delta))
+        with jax.named_scope("aggregate"):
+            acc = jax.tree.map(jnp.add, acc, term)
+        return acc, l
+
+    acc, client_loss = jax.lax.scan(
+        client, jax.tree.map(jnp.zeros_like, params),
+        (x, y, batch_idx, weights, keys, slots))
+    with jax.named_scope("aggregate"):
+        if stack.is_identity:
+            w_agg = jax.tree.map(agg.reduce, acc)
+        else:
+            w_agg = _aggregate_deltas(params, acc, wsum, stack, agg,
+                                      w_cohort)
+        loss_mean = agg.reduce(jnp.sum(weights * client_loss)) / wsum
+    return w_agg, loss_mean
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree's leaves (arrays, tracers or shape structs)."""
+    return sum(a.size * jnp.dtype(a.dtype).itemsize
+               for a in jax.tree.leaves(tree))
+
+
+def _device_bytes() -> Optional[int]:
+    """Memory of the first device, where its backend states it (a TPU
+    does, the CPU backend does not)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def client_loop(params, m: int) -> str:
+    """How a round trains its ``m`` clients on one device: ``"vmap"`` side
+    by side, ``"scan"`` one after another.
+
+    Side by side, every client holds its local copy of the model and its
+    gradient, and the aggregate its weighted share: three parameter trees
+    a client beside the global model.  Where that exceeds the device's
+    memory, the clients run one after another and share one sum.  Decided
+    from the shapes at trace time, once per compiled round; a backend that
+    states no memory (the CPU) runs side by side.
+    """
+    limit = _device_bytes()
+    need = (3 * m + 1) * _tree_bytes(params)
+    return "scan" if limit is not None and need > limit else "vmap"
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "loss", "tcfg", "cell_impl",
                                     "scfg"))
 def pipeline_round(params, x, y, batch_idx, weights, keys, lr, prox_mu,
-                   cfg: ForecasterConfig, loss: Callable,
+                   cfg: ModelSpec, loss: Callable,
                    tcfg: TransformConfig, cell_impl: str = "jnp",
                    scfg: Optional[SecureAggConfig] = None, round_key=None):
     """Full pipeline round, pseudo-distributed (vmap) execution.
@@ -330,7 +422,7 @@ def pipeline_round(params, x, y, batch_idx, weights, keys, lr, prox_mu,
 
 
 @functools.lru_cache(maxsize=None)
-def make_pipeline_round(mesh, cfg: ForecasterConfig, loss: Callable,
+def make_pipeline_round(mesh, cfg: ModelSpec, loss: Callable,
                         tcfg: TransformConfig = TransformConfig(),
                         acfg: AggregationConfig = AggregationConfig(),
                         cell_impl: str = "jnp",
@@ -409,7 +501,7 @@ class RoundEngine:
     the (region, clients) axis pair (``aggregation.make_mesh``).
     """
 
-    def __init__(self, fcfg: ForecasterConfig, flcfg: FLConfig, *,
+    def __init__(self, fcfg: ModelSpec, flcfg: FLConfig, *,
                  loss: Optional[Callable] = None, mesh=None,
                  cell_impl: str = "jnp",
                  audited_payload: Optional[float] = None):
@@ -507,8 +599,9 @@ class RoundEngine:
 
     def init(self, key):
         """Fresh global params + server-optimizer state."""
-        params = forecaster.init_forecaster(key, self.fcfg)
-        return params, server_opt_mod.init_server_state(params)
+        params = self.fcfg.init(key)
+        return params, server_opt_mod.init_server_state(params,
+                                                        self.flcfg.server)
 
     def put_clients(self, *arrays):
         """Copy client-stacked host arrays to the device(s) of this
@@ -719,7 +812,7 @@ def _seed_rngs(seed: int):
     return np.random.default_rng(hold_ss), np.random.default_rng(round_ss)
 
 
-def _as_provider(data, fcfg: ForecasterConfig) -> windows.ClientWindowProvider:
+def _as_provider(data, fcfg: ModelSpec) -> windows.ClientWindowProvider:
     if isinstance(data, windows.ClientWindowProvider):
         return data
     # in-memory sources cache every client: the raw series are already
@@ -758,7 +851,7 @@ def _restore_async_state(flat, n_pending: int, params):
     return async_engine.SemiSyncState.from_tree(tree)
 
 
-def run_federated_training(all_series, fcfg: ForecasterConfig,
+def run_federated_training(all_series, fcfg: ModelSpec,
                            flcfg: FLConfig, *, mesh=None,
                            log_every: int = 0,
                            checkpoint_path=None, checkpoint_every: int = 1,
@@ -799,8 +892,8 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
         mesh = aggregation_mod.make_mesh(flcfg.aggregation_config)
     engine = RoundEngine(fcfg, flcfg, mesh=mesh)
     ccfg = flcfg.client_opt
-    steps = partition.local_steps(provider.n_win_max, ccfg.batch_size,
-                                  ccfg.local_epochs)
+    steps = ccfg.local_steps or partition.local_steps(
+        provider.n_win_max, ccfg.batch_size, ccfg.local_epochs)
 
     n_total = provider.n_clients
     train_ids, held_ids = partition.holdout_clients(
@@ -945,6 +1038,10 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
         # fewer clients than configured); pads are cycled duplicates that
         # enter the round with weight 0, so the math is unchanged
         m_run = -(-m_sel // n_dev) * n_dev
+        # what fl.step runs, for the trace: the client loop each device's
+        # m_run / n_dev clients take, and the positions trained a round
+        loop = client_loop(params, m_run // n_dev)
+        tokens = m_run * steps * ccfg.batch_size * fcfg.lookback
         stopped = False
         for t in range(t0, flcfg.rounds):
             # host spans on the profiler's clock (about a microsecond each
@@ -982,7 +1079,8 @@ def run_federated_training(all_series, fcfg: ForecasterConfig,
                 with jax.profiler.TraceAnnotation("fl.put") as span:
                     put = engine.put_clients(s, bidx[pad_idx])
                     span.set_metadata(bytes=sum(a.nbytes for a in put))
-                with jax.profiler.TraceAnnotation("fl.step"):
+                with jax.profiler.TraceAnnotation(
+                        "fl.step", client_loop=loop, tokens=tokens):
                     params, sstate, l = engine.step(
                         params, sstate, put[0], None, put[1], w, round_idx=t,
                         stream=cid if cid >= 0 else 0)

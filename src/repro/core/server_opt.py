@@ -63,24 +63,45 @@ def uses_weighted_aggregation(flcfg: Union[FLConfig, ServerOptConfig]) -> bool:
     return as_server_config(flcfg).name in WEIGHTED_AGG_OPTS
 
 
-def init_server_state(params) -> ServerState:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
-    return ServerState(m=jax.tree.map(zeros, params),
-                       v=jax.tree.map(zeros, params),
+def init_server_state(params, cfg: Union[FLConfig, ServerOptConfig]
+                      ) -> ServerState:
+    """Zero moments for the server rule ``cfg``.  A moment the rule never
+    reads is ``None`` in place of a parameter-sized tree of zeros: plain
+    FedAvg keeps no moment, and at a model of a few GB the two zero trees
+    would not fit beside the round."""
+    zeros = lambda: jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
+                                 params)
+    cfg = as_server_config(cfg)
+    adaptive = cfg.name in ("fedadam", "fedyogi")
+    return ServerState(m=zeros() if adaptive or cfg.momentum > 0.0 else None,
+                       v=zeros() if adaptive else None,
                        t=jnp.zeros((), jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("flcfg",))
 def server_update(w_global, w_agg, state: ServerState,
                   flcfg: Union[FLConfig, ServerOptConfig]
                   ) -> Tuple[Any, ServerState]:
     """Apply one server step to the pseudo-gradient ``w_global - w_agg``.
 
     Accepts the flat ``FLConfig`` facade or the typed ``ServerOptConfig``
-    stage view.  Returns ``(new_global_params, new_state)``.  Dispatch on the
-    rule name happens at trace time (the config is static), so each rule
-    compiles to its own minimal program.
+    stage view.  Returns ``(new_global_params, new_state)``.  Plain FedAvg
+    at server lr 1 (``w <- w_agg``) returns the aggregate's own arrays: a
+    jitted step would return a copy, one more parameter tree on the device
+    while the round's arrays are still held.  Every other rule runs
+    jitted, dispatched on its name at trace time (the config is static),
+    so each compiles to its own minimal program.
     """
+    cfg = as_server_config(flcfg)
+    if (cfg.name in ("fedavg", "fedavg_weighted", "fedprox")
+            and cfg.lr == 1.0 and cfg.momentum == 0.0):
+        return w_agg, state._replace(t=state.t + 1)
+    return _server_step(w_global, w_agg, state, flcfg)
+
+
+@functools.partial(jax.jit, static_argnames=("flcfg",))
+def _server_step(w_global, w_agg, state: ServerState,
+                 flcfg: Union[FLConfig, ServerOptConfig]
+                 ) -> Tuple[Any, ServerState]:
     cfg = as_server_config(flcfg)
     opt = cfg.name
     if opt not in SERVER_OPTS:
@@ -96,8 +117,6 @@ def server_update(w_global, w_agg, state: ServerState,
                              state.m, g)
             new = jax.tree.map(lambda w, mm: w - lr * mm, w_global, m)
             return new, ServerState(m=m, v=state.v, t=t)
-        if lr == 1.0:                      # exact Alg. 1: w <- w_agg
-            return w_agg, ServerState(m=state.m, v=state.v, t=t)
         new = jax.tree.map(lambda w, gg: w - lr * gg, w_global, g)
         return new, ServerState(m=state.m, v=state.v, t=t)
 

@@ -105,9 +105,10 @@ CHUNK_THRESHOLD = 4096  # use chunked attention for sequences >= this
 USE_FLASH_KERNEL = bool(os.environ.get("REPRO_FLASH"))
 
 
-def _causal_attend(q, k, v, scale, window: int, dtype):
+def _causal_attend(q, k, v, scale, window: int, dtype, q_chunk: int = 0):
     """Causal attention, q-chunked above CHUNK_THRESHOLD to bound the score
-    materialization at (B, Q_CHUNK, H, S) instead of (B, S, H, S)."""
+    materialization at (B, Q_CHUNK, H, S) instead of (B, S, H, S).
+    ``q_chunk`` > 0 chunks at that many query rows whenever it divides S."""
     B, S = q.shape[:2]
     if USE_FLASH_KERNEL and S % 128 == 0 and v.shape[-1] == q.shape[-1]:
         from repro.kernels import ops as kops
@@ -131,11 +132,12 @@ def _causal_attend(q, k, v, scale, window: int, dtype):
         w = jax.nn.softmax(s, axis=-1).astype(dtype)
         return constrain_heads(_gqa_out(w, v))          # (B,qc,H,hd)
 
-    if S < CHUNK_THRESHOLD or S % Q_CHUNK:
+    qc = q_chunk or Q_CHUNK
+    if (S <= qc or S % qc) or (not q_chunk and S < CHUNK_THRESHOLD):
         return block((q, 0))
-    n = S // Q_CHUNK
-    qb = q.reshape(B, n, Q_CHUNK, *q.shape[2:]).swapaxes(0, 1)
-    offs = jnp.arange(n, dtype=jnp.int32) * Q_CHUNK
+    n = S // qc
+    qb = q.reshape(B, n, qc, *q.shape[2:]).swapaxes(0, 1)
+    offs = jnp.arange(n, dtype=jnp.int32) * qc
     ob = jax.lax.map(block, (qb, offs))                 # (n,B,qc,H,hd_v)
     return ob.swapaxes(0, 1).reshape(B, S, ob.shape[-2], ob.shape[-1])
 
@@ -168,6 +170,25 @@ def attention_forward(params, x, cfg: ModelConfig, *, cache=None,
             pos_ids, jnp.arange(S, dtype=pos_ids.dtype), (0,))
         new_cache = {"k": kc, "v": vc, "pos_ids": pos_ids}
     return out, new_cache
+
+
+def nope_attention(params, x, cfg: ModelConfig, *, scale: float,
+                   q_chunk: int = 0):
+    """Full-sequence causal GQA with no position embedding (NoPE: position
+    reaches the model through the causal mask and the other mixers only),
+    scores scaled by ``scale`` in place of 1/sqrt(head_dim), no biases and
+    no qk-norm.  x: (B, S, d) -> (B, S, d); ``init_attention``'s params."""
+    B, S, _ = x.shape
+    hd = cfg.resolved_head_dim
+    q = jnp.einsum("bsd,dh->bsh", x, params["wq"].astype(x.dtype))
+    k = jnp.einsum("bsd,dh->bsh", x, params["wk"].astype(x.dtype))
+    v = jnp.einsum("bsd,dh->bsh", x, params["wv"].astype(x.dtype))
+    q = q.reshape(B, S, cfg.n_heads, hd)
+    k = k.reshape(B, S, cfg.n_kv_heads, hd)
+    v = v.reshape(B, S, cfg.n_kv_heads, hd)
+    o = _causal_attend(q, k, v, scale, 0, x.dtype, q_chunk=q_chunk)
+    o = o.reshape(B, S, cfg.n_heads * hd)
+    return jnp.einsum("bsh,hd->bsd", o, params["wo"].astype(x.dtype))
 
 
 def attention_decode(params, x, cache, pos, cfg: ModelConfig, *, window: int = 0):

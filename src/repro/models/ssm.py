@@ -6,11 +6,17 @@ chunks a single ``lax.scan`` carries the (nh, hd, ds) state.  Decode is the
 plain single-step recurrence against a conv ring buffer + SSM state.
 
 Layout: x (B, S, d) → in_proj → [z | xBC | dt]; depthwise causal conv over
-xBC; heads nh = d_inner / head_dim; per-head scalar decay a_t = exp(-softplus
-(A) · dt_t) (Mamba2's scalar-identity A).  Gated RMSNorm before out_proj.
+xBC; heads nh = d_inner / head_dim; per-head scalar decay a_t = exp(A · dt_t)
+with A = -exp(A_log) and dt_t = softplus(dt_t + dt_bias) (Mamba2's
+scalar-identity A).  Gated RMSNorm before out_proj.
+
+Initialisation follows the published Mamba2: A uniform in [1, 16] (stored
+as A_log), dt log-uniform in [1e-3, 0.1] (floored at 1e-4) and stored as
+the inverse softplus in ``dt_bias``, D = 1.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -19,6 +25,10 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig, SSMConfig
 from repro.models.layers import dense_init, rms_norm
 from repro.sharding import constrain
+
+A_INIT = (1.0, 16.0)          # A = -exp(a_log), uniform in this range
+DT_INIT = (1e-3, 0.1)         # dt log-uniform in this range ...
+DT_FLOOR = 1e-4               # ... and at least this
 
 
 def _dims(cfg: ModelConfig):
@@ -32,18 +42,23 @@ def init_ssm(key, cfg: ModelConfig, dtype=jnp.float32) -> Dict:
     s, d_in, nh = _dims(cfg)
     d = cfg.d_model
     conv_dim = d_in + 2 * s.n_groups * s.state_dim
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
+    a = jax.random.uniform(ks[2], (nh,), jnp.float32, A_INIT[0], A_INIT[1])
+    dt = jnp.exp(jax.random.uniform(ks[3], (nh,), jnp.float32,
+                                    math.log(DT_INIT[0]),
+                                    math.log(DT_INIT[1])))
+    dt = jnp.maximum(dt, DT_FLOOR)
     return {
         "in_proj": dense_init(ks[0], d, 2 * d_in + 2 * s.n_groups * s.state_dim
                               + nh, dtype=dtype),
         "conv_w": (jax.random.normal(ks[1], (s.conv_width, conv_dim),
                                      jnp.float32) * 0.2).astype(dtype),
         "conv_b": jnp.zeros((conv_dim,), dtype),
-        "a_log": jnp.zeros((nh,), jnp.float32),          # A = -softplus? see below
-        "dt_bias": jnp.full((nh,), -2.0, jnp.float32),   # softplus^-1(~0.12)
+        "a_log": jnp.log(a),                             # A = -exp(a_log)
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),        # softplus^-1(dt)
         "d_skip": jnp.ones((nh,), jnp.float32),
         "norm_w": jnp.ones((d_in,), dtype),
-        "out_proj": dense_init(ks[3], d_in, d, scale=d_in ** -0.5, dtype=dtype),
+        "out_proj": dense_init(ks[4], d_in, d, scale=d_in ** -0.5, dtype=dtype),
     }
 
 
@@ -87,8 +102,7 @@ def _heads(xBC, dt, params, cfg: ModelConfig):
     Cm = jnp.repeat(Cm, rep, axis=-2)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (...,nh)
     a = -jnp.exp(params["a_log"])                        # (nh,) negative decay
-    decay = jnp.exp(a * dt)                              # (...,nh) in (0,1)
-    return x, Bm, Cm, dt, decay
+    return x, Bm, Cm, dt, a * dt                         # log of the decay
 
 
 def ssm_forward(params, x, cfg: ModelConfig, *, state=None
@@ -107,19 +121,18 @@ def ssm_forward(params, x, cfg: ModelConfig, *, state=None
     z, xBC_raw, dt_raw = _split_proj(zxbcdt, cfg)
     xBC = _conv(xBC_raw, params["conv_w"].astype(x.dtype), params["conv_b"]
                 .astype(x.dtype))
-    xh, Bm, Cm, dt, decay = _heads(xBC, dt_raw, params, cfg)
+    xh, Bm, Cm, dt, logdec = _heads(xBC, dt_raw, params, cfg)
     xh = constrain(xh, "batch", None, "act_heads", None)
     if pad:
-        # pad to a chunk multiple with IDENTITY steps: decay=1, contribution=0
+        # pad to a chunk multiple with IDENTITY steps: decay=1 (log 0),
+        # contribution=0
         pz = lambda t: jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-        xh, Bm, Cm, dt = map(pz, (xh, Bm, Cm, dt))
-        decay = jnp.pad(decay, ((0, 0), (0, pad), (0, 0)),
-                        constant_values=1.0)
+        xh, Bm, Cm, dt, logdec = map(pz, (xh, Bm, Cm, dt, logdec))
 
     # chunk to (nc, B, Q, ...) and scan over chunks — bounds the quadratic
     # intra-chunk intermediate at one (B, Q, Q, nh) block at a time
     ch = lambda t: t.reshape(B, nc, Q, *t.shape[2:]).swapaxes(0, 1)
-    xh_c, Bm_c, Cm_c, dt_c, decay_c = map(ch, (xh, Bm, Cm, dt, decay))
+    xh_c, Bm_c, Cm_c, dt_c, logdec_c = map(ch, (xh, Bm, Cm, dt, logdec))
     xdt_c = xh_c * dt_c[..., None].astype(xh_c.dtype)    # fold dt into x
 
     init = (jnp.zeros((B, nh, s.head_dim, s.state_dim), jnp.float32)
@@ -127,12 +140,18 @@ def ssm_forward(params, x, cfg: ModelConfig, *, state=None
     iq = jnp.arange(Q)
     causal = iq[:, None] >= iq[None, :]
 
+    # rematerialised: the backward pass keeps the (B, nh, hd, ds) state at
+    # each chunk's start and recomputes the chunk's (B, Q, Q, nh) terms,
+    # in place of stacking them for every chunk
+    @jax.checkpoint
     def scan_body(st, inp):
-        xdt, Bc, Cc, dec = inp                           # (B,Q,...) one chunk
-        logdec = jnp.log(jnp.maximum(dec, 1e-20))        # (B,Q,nh) fp32
-        cum = jnp.cumsum(logdec, axis=1)                 # inclusive
+        xdt, Bc, Cc, logdec = inp                        # (B,Q,...) one chunk
+        cum = jnp.cumsum(logdec, axis=1)                 # inclusive, fp32
         seg = cum[:, :, None, :] - cum[:, None, :, :]    # (B,Qi,Qj,nh)
-        L = jnp.where(causal[None, :, :, None], jnp.exp(seg), 0.0)
+        # mask BEFORE the exp: above the diagonal seg is positive, its exp
+        # overflows, and a where after the exp turns the overflow into a
+        # NaN gradient
+        L = jnp.exp(jnp.where(causal[None, :, :, None], seg, -jnp.inf))
         cb = jnp.einsum("bqhn,bkhn->bqkh", Cc, Bc)       # (B,Qi,Qj,nh)
         y_intra = jnp.einsum("bqkh,bkhp->bqhp", cb * L.astype(cb.dtype), xdt)
         # inter-chunk: C_t · decay_from_chunk_start · st
@@ -148,8 +167,9 @@ def ssm_forward(params, x, cfg: ModelConfig, *, state=None
         st = st * jnp.exp(cum[:, -1, :])[..., None, None] + contrib
         return st, y_intra + y_inter
 
-    final_state, y_c = jax.lax.scan(scan_body, init,
-                                    (xdt_c, Bm_c, Cm_c, decay_c))
+    with jax.named_scope("ssd"):
+        final_state, y_c = jax.lax.scan(scan_body, init,
+                                        (xdt_c, Bm_c, Cm_c, logdec_c))
     y = y_c.swapaxes(0, 1).reshape(B, S + pad, nh, s.head_dim)[:, :S]
     y = y + xh[:, :S] * params["d_skip"][:, None].astype(y.dtype)
     y = y.reshape(B, S, d_in)
@@ -169,7 +189,8 @@ def ssm_decode(params, x, state, cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
     xBC, new_conv = _conv_step(xBC_raw, state["conv"],
                                params["conv_w"].astype(x.dtype),
                                params["conv_b"].astype(x.dtype))
-    xh, Bm, Cm, dt, decay = _heads(xBC, dt_raw, params, cfg)   # (B,nh,hd) etc.
+    xh, Bm, Cm, dt, logdec = _heads(xBC, dt_raw, params, cfg)  # (B,nh,hd) etc.
+    decay = jnp.exp(logdec)
 
     st = state["ssm"]                                    # (B,nh,hd,ds) fp32
     contrib = jnp.einsum("bhn,bhp->bhpn", Bm.astype(jnp.float32),

@@ -40,7 +40,6 @@ import jax.numpy as jnp
 
 from repro import checkpoint
 from repro.configs.base import ForecasterConfig
-from repro.models import forecaster
 
 __all__ = ["GLOBAL_SLOT", "ModelHandle", "ModelRegistry",
            "quantize_params", "dequantize_params"]
@@ -209,7 +208,7 @@ class ModelRegistry:
                 return []
         flat, meta = checkpoint.load_arrays(path)
         meta = meta or {}
-        template = forecaster.param_template(cfg)
+        template = cfg.param_template()
         entries = [(int(cid), f"done/{cid}/params/")
                    for cid in meta.get("done", [])]
         if "cluster" in meta:

@@ -204,7 +204,7 @@ def test_engine_dp_noise_replays_under_fixed_seed(fl_data):
                      n_clusters=0, loss="mse", dp_clip=1.0, dp_noise=0.5)
     eng = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS)
     counts = np.full(4, float(x.shape[1]), np.float32)
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, flcfg)
     p1, _, l1 = eng.step(params, s0, x, y, bidx, counts, round_idx=3)
     p2, _, l2 = eng.step(params, s0, x, y, bidx, counts, round_idx=3)
     jax.tree.map(lambda u, v: np.testing.assert_array_equal(u, v), p1, p2)
@@ -274,7 +274,7 @@ def test_hierarchical_matches_flat_on_2x4_mesh(fl_data, tcfg):
     idx = np.resize(np.arange(4), 8)
     counts = np.full(8, float(x.shape[1]), np.float32)
     counts[4:] = 0.0
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, e_flat.flcfg)
     args = (params, s0, x[idx], y[idx], bidx[idx], counts)
     p_f, _, l_f = e_flat.step(*args, round_idx=0)
     p_h, _, l_h = e_hier.step(*args, round_idx=0)
@@ -298,7 +298,7 @@ def test_full_pipeline_round_runs_and_is_finite(fl_data):
     idx = np.resize(np.arange(4), m)
     counts = np.full(m, float(x.shape[1]), np.float32)
     counts[4:] = 0.0
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, flcfg)
     p, _, l = eng.step(params, s0, x[idx], y[idx], bidx[idx], counts,
                        round_idx=0)
     assert np.isfinite(float(l))

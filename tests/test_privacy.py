@@ -205,7 +205,7 @@ def test_masked_round_equals_clear_vmap(fl_data):
               server_opt="fedavg_weighted")
     e_clear, e_mask = _engines(kw)
     counts = np.full(4, float(x.shape[1]), np.float32)
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, e_clear.flcfg)
     p_c, _, l_c = e_clear.step(params, s0, x, y, bidx, counts, round_idx=0)
     p_m, _, l_m = e_mask.step(params, s0, x, y, bidx, counts, round_idx=0)
     np.testing.assert_allclose(float(l_c), float(l_m), rtol=1e-6)
@@ -248,7 +248,7 @@ def test_masked_equals_clear_on_mesh_topologies(fl_data, agg_kw, mesh_shape,
     idx = np.resize(np.arange(4), 8)
     counts = np.full(8, float(x.shape[1]), np.float32)
     counts[4:] = 0.0                                 # mesh pads
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, e_clear.flcfg)
     args = (params, s0, x[idx], y[idx], bidx[idx], counts)
     p_c, _, l_c = e_clear.step(*args, round_idx=0)
     p_m, _, l_m = e_mask.step(*args, round_idx=0)
@@ -566,7 +566,7 @@ def test_masked_round_equals_clear_bitwise_vmap(fl_data):
     params, x, y, bidx = fl_data
     e_clear, e_mask = _ring_engines(RING_KW)
     counts = np.asarray([17.0, 5.0, 29.0, 11.0], np.float32)
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, e_clear.flcfg)
     p_c, _, l_c = e_clear.step(params, s0, x, y, bidx, counts, round_idx=0)
     p_m, _, l_m = e_mask.step(params, s0, x, y, bidx, counts, round_idx=0)
     np.testing.assert_array_equal(np.asarray(l_c), np.asarray(l_m))
@@ -606,7 +606,7 @@ def test_masked_equals_clear_bitwise_on_mesh(fl_data, agg_kw, mesh_shape,
     idx = np.resize(np.arange(4), 8)
     counts = np.full(8, float(x.shape[1]), np.float32)
     counts[4:] = 0.0                                 # mesh pads
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, e_clear.flcfg)
     args = (params, s0, x[idx], y[idx], bidx[idx], counts)
     p_c, _, l_c = e_clear.step(*args, round_idx=0)
     p_m, _, l_m = e_mask.step(*args, round_idx=0)

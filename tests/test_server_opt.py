@@ -80,7 +80,7 @@ def test_fedprox_mu0_equals_fedavg(fl_data):
     for opt in ("fedavg_weighted", "fedprox"):
         eng = fedavg.RoundEngine(FCFG, _engine_flcfg(server_opt=opt,
                                                      prox_mu=0.0), loss=LOSS)
-        state = server_opt.init_server_state(params)
+        state = server_opt.init_server_state(params, eng.flcfg)
         p, _, l = eng.step(params, state, x, y, bidx, counts)
         outs[opt] = (p, float(l))
     tree_close(outs["fedprox"][0], outs["fedavg_weighted"][0],
@@ -112,7 +112,7 @@ def test_adaptive_first_step_recovers_averaging_one_client(fl_data, opt):
     flcfg = _engine_flcfg(server_opt=opt, server_beta1=0.0,
                           server_eps=1e6, server_lr=1e6)
     eng = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS)
-    state = server_opt.init_server_state(params)
+    state = server_opt.init_server_state(params, flcfg)
     p, _, _ = eng.step(params, state, x[:1], y[:1], bidx[:1],
                        np.ones(1, np.float32))
     p_loc, _ = local_update(params, x[0], y[0], bidx[0], jnp.float32(0.05),
@@ -123,9 +123,9 @@ def test_adaptive_first_step_recovers_averaging_one_client(fl_data, opt):
 def test_server_update_fedavg_lr1_returns_aggregate_exactly():
     w = {"a": jnp.arange(4.0), "b": jnp.ones((2, 3))}
     agg = jax.tree.map(lambda t: t + 0.5, w)
-    state = server_opt.init_server_state(w)
-    new, st2 = server_opt.server_update(w, agg, state,
-                                        _engine_flcfg(server_opt="fedavg"))
+    flcfg = _engine_flcfg(server_opt="fedavg")
+    state = server_opt.init_server_state(w, flcfg)
+    new, st2 = server_opt.server_update(w, agg, state, flcfg)
     jax.tree.map(np.testing.assert_array_equal, new, agg)
     assert int(st2.t) == 1
 
@@ -136,7 +136,7 @@ def test_server_momentum_accumulates_fedavgm():
     w = {"a": jnp.zeros(3)}
     flcfg = _engine_flcfg(server_opt="fedavg", server_lr=0.5,
                           server_momentum=0.9)
-    state = server_opt.init_server_state(w)
+    state = server_opt.init_server_state(w, flcfg)
     w1, state = server_opt.server_update(
         w, jax.tree.map(lambda t: t + 1.0, w), state, flcfg)
     w2, state = server_opt.server_update(
@@ -148,8 +148,9 @@ def test_server_momentum_accumulates_fedavgm():
 
 def test_server_update_rejects_unknown_opt():
     w = {"a": jnp.zeros(2)}
+    state = server_opt.init_server_state(w, _engine_flcfg(server_opt="fedavg"))
     with pytest.raises(ValueError):
-        server_opt.server_update(w, w, server_opt.init_server_state(w),
+        server_opt.server_update(w, w, state,
                                  _engine_flcfg(server_opt="fedsgdfoo"))
     with pytest.raises(ValueError):
         fedavg.RoundEngine(FCFG, _engine_flcfg(server_opt="fedsgdfoo"))
@@ -164,7 +165,7 @@ def test_vmap_and_shard_map_paths_agree(fl_data, opt):
     counts = np.full(4, float(x.shape[1]), np.float32)
     e_vmap = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS)
     e_shard = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS, mesh=MESH)
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, flcfg)
     p1, s1, l1 = e_vmap.step(params, s0, x, y, bidx, counts)
     p2, s2, l2 = e_shard.step(params, s0, x, y, bidx, counts)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
@@ -185,7 +186,7 @@ def test_shard_map_multi_device_matches_vmap(fl_data):
     counts = np.asarray([3.0, 1.0, 2.0, 2.0], np.float32)
     e_vmap = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS)
     e_shard = fedavg.RoundEngine(FCFG, flcfg, loss=LOSS, mesh=mesh)
-    s0 = server_opt.init_server_state(params)
+    s0 = server_opt.init_server_state(params, flcfg)
     p1, _, l1 = e_vmap.step(params, s0, x, y, bidx, counts)
     p2, _, l2 = e_shard.step(params, s0, x, y, bidx, counts)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)

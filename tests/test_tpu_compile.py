@@ -188,3 +188,32 @@ def test_serving_forward_compiles_for_v5e(one_chip):
         _spec((b, 1), f32, one_chip), _spec((b, 1), f32, one_chip),
         cfg=FCFG).compile()
     _fits_one_chip(compiled)
+
+
+def test_hybrid_round_compiles_for_v5e(one_chip, monkeypatch):
+    """The hybrid backbone's round as the silo cell runs it (its ten
+    layers at the published widths, a look-back of 2,048, B = 4, two
+    clients, which a 16 GiB chip trains one after another): the chip's
+    compiler takes it, and the layer scopes reach its ops' metadata."""
+    from repro.configs.base import HybridForecasterConfig
+    cfg = HybridForecasterConfig()
+    monkeypatch.setattr(fedavg, "_device_bytes", lambda: V5E_HBM_BYTES)
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          jax.eval_shape(cfg.init, jax.random.PRNGKey(0)))
+    m, K, B = 2, 4, 4
+    assert fedavg.client_loop(params, m) == "scan"
+    try:
+        compiled = fedavg.pipeline_round.lower(
+            params, _spec((m, 26_280), f32, one_chip), None,
+            _spec((m, K, B), jnp.int32, one_chip),
+            _spec((m,), f32, one_chip), _spec((m, 2), jnp.uint32, one_chip),
+            _spec((), f32, one_chip), _spec((), f32, one_chip),
+            cfg, losses.make_loss("ew_mse", 2.0), TransformConfig()
+        ).compile()
+    finally:
+        jax.clear_caches()
+    text = compiled.as_text()
+    for scope in ("/local_update/", "/hybrid/mamba/ssd/",
+                  "/hybrid/attention/", "/hybrid/mlp/"):
+        assert scope in text, scope

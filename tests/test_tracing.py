@@ -120,3 +120,44 @@ def test_round_program_names_its_stages(path, tcfg, scopes):
         assert f"/{scope}/" in text, scope
     if "transform" not in scopes:
         assert "/transform/" not in text
+
+
+def test_step_span_names_its_client_loop_and_tokens(traced, series):
+    """``fl.step`` says how the round trains its clients and how many
+    positions it trains: clients x steps x B x look-back."""
+    _, _, spans = traced
+    prov = windows.ClientWindowProvider.from_series(
+        series, FCFG.lookback, FCFG.horizon)
+    steps = partition.local_steps(prov.n_win_max, FLCFG.batch_size,
+                                  FLCFG.local_epochs)
+    want = FLCFG.clients_per_round * steps * FLCFG.batch_size * FCFG.lookback
+    stats = [s[3] for s in spans if s[2] == "fl.step"]
+    assert [(s["client_loop"], s["tokens"]) for s in stats] == \
+        [("vmap", want)] * FLCFG.rounds
+
+
+@pytest.mark.parametrize("loop", ["vmap", "scan"])
+def test_hybrid_round_names_its_layers(loop, monkeypatch):
+    """The hybrid backbone's scopes reach the compiled round's ``op_name``
+    metadata inside ``local_update`` on both client loops: ``hybrid/mamba``
+    holding ``ssd``, ``hybrid/attention`` and ``hybrid/mlp``."""
+    from repro.configs.base import HybridForecasterConfig, SSMConfig
+    cfg = HybridForecasterConfig(
+        layer_types=("mamba", "attention"), d_model=16, n_heads=2,
+        n_kv_heads=1, head_dim=8, d_ff=32,
+        ssm=SSMConfig(state_dim=4, head_dim=8, expand=2, conv_width=4,
+                      chunk_size=8, n_groups=1),
+        lookback=16, horizon=4)
+    monkeypatch.setattr(fedavg, "_device_bytes",
+                        lambda: 1 if loop == "scan" else None)
+    m = 2
+    params = cfg.init(jax.random.PRNGKey(0))
+    lowered = fedavg.pipeline_round.lower(
+        params, jnp.zeros((m, 40), jnp.float32), None,
+        jnp.zeros((m, 2, 3), jnp.int32), jnp.ones((m,), jnp.float32),
+        jnp.zeros((m, 2), jnp.uint32), jnp.float32(0.01), jnp.float32(0.0),
+        cfg, losses.make_loss("ew_mse", 2.0), TransformConfig())
+    text = lowered.compile().as_text()
+    for scope in ("/local_update/", "/hybrid/mamba/ssd/",
+                  "/hybrid/attention/", "/hybrid/mlp/", "/aggregate/"):
+        assert scope in text, scope
