@@ -1039,9 +1039,12 @@ def run_federated_training(all_series, fcfg: ModelSpec,
         # enter the round with weight 0, so the math is unchanged
         m_run = -(-m_sel // n_dev) * n_dev
         # what fl.step runs, for the trace: the client loop each device's
-        # m_run / n_dev clients take, and the positions trained a round
+        # m_run / n_dev clients take, the positions trained a round, and
+        # whether the fused kernels or the scan differentiate the LSTM
         loop = client_loop(params, m_run // n_dev)
         tokens = m_run * steps * ccfg.batch_size * fcfg.lookback
+        recurrence = ("fused" if forecaster.fused_recurrence(
+            fcfg, engine.cell_impl) else "scan")
         stopped = False
         for t in range(t0, flcfg.rounds):
             # host spans on the profiler's clock (about a microsecond each
@@ -1080,7 +1083,8 @@ def run_federated_training(all_series, fcfg: ModelSpec,
                     put = engine.put_clients(s, bidx[pad_idx])
                     span.set_metadata(bytes=sum(a.nbytes for a in put))
                 with jax.profiler.TraceAnnotation(
-                        "fl.step", client_loop=loop, tokens=tokens):
+                        "fl.step", client_loop=loop, tokens=tokens,
+                        recurrence=recurrence):
                     params, sstate, l = engine.step(
                         params, sstate, put[0], None, put[1], w, round_idx=t,
                         stream=cid if cid >= 0 else 0)

@@ -12,6 +12,11 @@ from typing import Optional
 import jax
 
 
+def on_tpu() -> bool:
+    """Whether JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
+
+
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     """``None`` -> interpret unless the default backend is a TPU.
 
@@ -19,10 +24,10 @@ def resolve_interpret(interpret: Optional[bool] = None) -> bool:
     (the compile rehearsal in ``tests/test_tpu_compile.py``); an explicit
     ``interpret=True`` on a TPU is refused.
     """
-    on_tpu = jax.default_backend() == "tpu"
+    tpu = on_tpu()
     if interpret is None:
-        return not on_tpu
-    if interpret and on_tpu:
+        return not tpu
+    if interpret and tpu:
         raise ValueError("Pallas kernels never run in interpret mode on a "
                          "TPU; pass interpret=None")
     return bool(interpret)

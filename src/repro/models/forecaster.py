@@ -9,6 +9,12 @@ of ``(x_t, state, params)``; ``cell_impl="jnp"`` uses the pure-jnp path (the
 oracle), ``cell_impl="pallas"`` routes through the fused Pallas TPU cell in
 ``repro.kernels`` — compiled on a TPU, interpreted on other backends, as the
 platform decides when the forward is traced.
+
+Training a one-layer LSTM on a TPU differentiates its whole look-back
+through the fused sequence kernels (``kernels/lstm_seq.py``): forward and
+backward keep the gates, h and c in VMEM.  :func:`fused_recurrence`
+decides it from the spec, the implementation and the platform; the value
+the forward returns is the scan's either way.
 """
 from __future__ import annotations
 
@@ -91,32 +97,78 @@ def _pallas_cells():
 
 
 # ------------------------------------------------------------------ forward
+def fused_recurrence(cfg, cell_impl: str) -> bool:
+    """Whether the LSTM layer is differentiated by the fused sequence
+    kernels: on a TPU, for a one-layer LSTM forecaster on the jnp cells,
+    at widths the kernels' layout holds (``lstm_seq.fits``).  Every other
+    case (the GRU, deeper stacks, ``cell_impl="pallas"``, another model
+    spec, another backend) runs the scan.  Read at trace time, once per
+    compiled round."""
+    from repro.kernels import lstm_seq, platform
+    return (isinstance(cfg, ForecasterConfig) and cfg.cell == "lstm"
+            and cfg.n_layers == 1 and cell_impl == "jnp"
+            and platform.on_tpu()
+            and lstm_seq.fits(cfg.hidden_dim, cfg.lookback, cfg.input_dim))
+
+
+def _layer(h_seq, p, cell: str, lstm_step, gru_step):
+    """One recurrent layer over the sequence: (B, L, in) -> (B, L, H)."""
+    B = h_seq.shape[0]
+    H = p["wh"].shape[0]
+    dtype = h_seq.dtype
+    if cell == "lstm":
+        def step(carry, x_t):
+            h, c = carry
+            h, c = lstm_step(x_t, h, c, p)
+            return (h, c), h
+        init = (jnp.zeros((B, H), dtype), jnp.zeros((B, H), dtype))
+    else:
+        def step(carry, x_t):
+            h = gru_step(x_t, carry[0], p)
+            return (h, carry[1]), h
+        init = (jnp.zeros((B, H), dtype), jnp.zeros((B, 0), dtype))
+    (_, _), hs = jax.lax.scan(step, init, h_seq.swapaxes(0, 1))
+    return hs.swapaxes(0, 1)
+
+
+@jax.custom_vjp
+def _lstm_last_h(x, wx, wh, b):
+    """The LSTM layer's last hidden state: the scan forward, the fused
+    kernels under differentiation."""
+    p = {"wx": wx, "wh": wh, "b": b}
+    return _layer(x, p, "lstm", lstm_cell, gru_cell)[:, -1]
+
+
+def _lstm_last_h_fwd(x, wx, wh, b):
+    from repro.kernels import ops as kops
+    h = kops.lstm_seq_forward(x, wx, wh, b)
+    return h.astype(x.dtype), (x, wx, wh, b)
+
+
+def _lstm_last_h_bwd(res, dh):
+    from repro.kernels import ops as kops
+    grads = kops.lstm_seq_backward(*res, dh)
+    return tuple(g.astype(r.dtype) for g, r in zip(grads, res))
+
+
+_lstm_last_h.defvjp(_lstm_last_h_fwd, _lstm_last_h_bwd)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "cell_impl"))
 def forecast(params, x, cfg: ForecasterConfig, cell_impl: str = "jnp"):
     """x: (B, L, input_dim) -> (B, horizon)."""
-    B = x.shape[0]
-    H = cfg.hidden_dim
-    if cell_impl == "pallas":
-        lstm_step, gru_step = _pallas_cells()
+    if fused_recurrence(cfg, cell_impl):
+        p = params["layers"][0]
+        h_last = _lstm_last_h(x, p["wx"], p["wh"], p["b"])
     else:
-        lstm_step, gru_step = lstm_cell, gru_cell
-
-    h_seq = x
-    for p in params["layers"]:
-        if cfg.cell == "lstm":
-            def step(carry, x_t, p=p):
-                h, c = carry
-                h, c = lstm_step(x_t, h, c, p)
-                return (h, c), h
-            init = (jnp.zeros((B, H), x.dtype), jnp.zeros((B, H), x.dtype))
+        if cell_impl == "pallas":
+            lstm_step, gru_step = _pallas_cells()
         else:
-            def step(carry, x_t, p=p):
-                h = gru_step(x_t, carry[0], p)
-                return (h, carry[1]), h
-            init = (jnp.zeros((B, H), x.dtype), jnp.zeros((B, 0), x.dtype))
-        (_, _), hs = jax.lax.scan(step, init, h_seq.swapaxes(0, 1))
-        h_seq = hs.swapaxes(0, 1)                       # (B, L, H)
-    h_last = h_seq[:, -1]
+            lstm_step, gru_step = lstm_cell, gru_cell
+        h_seq = x
+        for p in params["layers"]:
+            h_seq = _layer(h_seq, p, cfg.cell, lstm_step, gru_step)
+        h_last = h_seq[:, -1]
     return h_last @ params["head"]["w"] + params["head"]["b"]
 
 

@@ -176,6 +176,42 @@ def test_r1_series_round_compiles_for_v5e(one_chip):
         windows.as_text().count(" while(")
 
 
+@pytest.mark.parametrize("loop", ["vmap", "scan"])
+def test_fused_local_update_compiles_for_v5e(one_chip, monkeypatch, loop):
+    """The local update the R1 round runs on a TPU, its LSTM differentiated
+    by the fused sequence kernels (``forecaster.fused_recurrence``): 256
+    vmapped clients x 410 steps of B = 64 on their series, the kernels
+    taking blocks of clients; or one client at a time, as the scan client
+    loop runs it.  The kernels compile, and the update fits one chip."""
+    from repro.core import client
+    from repro.kernels import platform
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    assert forecaster.fused_recurrence(FCFG, "jnp")
+    f32 = jnp.float32
+    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
+                          forecaster.param_template(FCFG))
+    loss = losses.make_loss("ew_mse", 2.0)
+    one = lambda p, s, i, lr: client.local_update(p, s, None, i, lr, FCFG,
+                                                  loss)
+    T = R1_WINDOWS + FCFG.lookback + FCFG.horizon - 1
+    if loop == "vmap":
+        update = jax.vmap(one, in_axes=(None, 0, 0, None))
+        shapes = ((R1_M, T), (R1_M, R1_STEPS, R1_BATCH))
+    else:
+        update = one
+        shapes = ((T,), (R1_STEPS, R1_BATCH))
+    jax.clear_caches()              # the path is chosen when traced
+    try:
+        compiled = jax.jit(update).lower(
+            params, _spec(shapes[0], f32, one_chip),
+            _spec(shapes[1], jnp.int32, one_chip),
+            _spec((), f32, one_chip)).compile()
+    finally:
+        jax.clear_caches()
+    assert compiled.as_text().count("tpu_custom_call") >= 2  # fwd and bwd
+    _fits_one_chip(compiled)
+
+
 def test_serving_forward_compiles_for_v5e(one_chip):
     """The engine's fp32 forward at its largest bucket (256 requests)."""
     f32 = jnp.float32

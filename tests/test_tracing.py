@@ -136,6 +136,38 @@ def test_step_span_names_its_client_loop_and_tokens(traced, series):
         [("vmap", want)] * FLCFG.rounds
 
 
+def test_step_span_names_the_scan_recurrence_on_the_cpu(traced):
+    """``fl.step`` says what differentiates the LSTM each round; off a
+    TPU it is the scan (``forecaster.fused_recurrence``)."""
+    _, _, spans = traced
+    assert [s[3]["recurrence"] for s in spans if s[2] == "fl.step"] == \
+        ["scan"] * FLCFG.rounds
+
+
+def test_step_span_names_the_fused_recurrence_where_it_runs(
+        series, tmp_path, monkeypatch):
+    """Where the fused kernels differentiate the LSTM (here interpreted,
+    the choice steered to them), every round's ``fl.step`` says so."""
+    from jax._src.profiler import ProfileData
+    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c, i: True)
+    flcfg = FLConfig(n_clients=8, clients_per_round=4, rounds=1,
+                     local_epochs=1, batch_size=64, n_clusters=0, seed=5)
+    jax.clear_caches()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            res = fedavg.run_federated_training(
+                series, ForecasterConfig(), flcfg)[-1]
+    finally:
+        jax.clear_caches()
+    assert np.all(np.isfinite(res.loss_history))
+    path, = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                      recursive=True)
+    stats = [dict(e.stats) for pl in ProfileData.from_file(path).planes
+             if pl.name.startswith("/host:") for ln in pl.lines
+             for e in ln.events if e.name == "fl.step"]
+    assert [s["recurrence"] for s in stats] == ["fused"] * flcfg.rounds
+
+
 @pytest.mark.parametrize("loop", ["vmap", "scan"])
 def test_hybrid_round_names_its_layers(loop, monkeypatch):
     """The hybrid backbone's scopes reach the compiled round's ``op_name``
