@@ -1,23 +1,25 @@
 #!/usr/bin/env python3
-"""Smoke run of the main path on a TPU: train, Pallas cells, serve.
+"""Smoke run of the main path on a TPU: train, reference, serve.
 
 Drives the paper's forecaster (LSTM, H = 64, look-back 8, horizon 4) at full
 width through the calls a user makes — ``fedavg.run_federated_training``,
-``evaluate_unseen_clients``, the fused Pallas cells, ``ModelRegistry`` and
-``ServingEngine`` — and checks every result against a reference.  Each
-phase prints one JSON line with its compile seconds and its steady-state
-seconds apart; the last line is ``{"ok": true, "device": {...}}``.  A phase
-that fails raises, and the script exits non-zero without that line.  With no
-TPU it stops at once: nothing runs on the CPU in its place.
+``evaluate_unseen_clients``, the local update (its LSTM differentiated by
+the fused Pallas kernels), ``ModelRegistry`` and ``ServingEngine`` — and
+checks every result against a reference.  Each phase prints one JSON line
+with its compile seconds and its steady-state seconds apart; the last line
+is ``{"ok": true, "device": {...}}``.  A phase that fails raises, and the
+script exits non-zero without that line.  With no TPU it stops at once:
+nothing runs on the CPU in its place.
 
 Every phase runs in this one process, which holds the chip.
 
-  python chip_smoke.py               # one chip: train, reference, pallas, serve
+  python chip_smoke.py               # one chip: train, reference, serve
   python chip_smoke.py --four-chips  # four chips: mesh rounds vs one chip
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
 import json
@@ -133,11 +135,25 @@ def _on_tpu(tree) -> bool:
                for x in jax.tree.leaves(tree))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
-def _local_updates(params, x, y, bidx, lr, cfg, loss, cell_impl):
+@contextlib.contextmanager
+def _scan_recurrence():
+    """Trace the LSTM's recurrence as the jnp scan while inside.  The fused
+    kernels are chosen from the default backend, the chip's, when traced;
+    the host CPU backend cannot run them."""
+    chosen = forecaster.fused_recurrence
+    forecaster.fused_recurrence = lambda cfg: False
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        forecaster.fused_recurrence = chosen
+        jax.clear_caches()
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "loss"))
+def _local_updates(params, x, y, bidx, lr, cfg, loss):
     """The local-update stage of the round, vmapped over clients."""
-    one = functools.partial(client.local_update, cfg=cfg, loss=loss,
-                            cell_impl=cell_impl)
+    one = functools.partial(client.local_update, cfg=cfg, loss=loss)
     return jax.vmap(one, in_axes=(None, 0, 0, 0, None))(params, x, y, bidx,
                                                         lr)
 
@@ -219,11 +235,16 @@ def phase_reference(meter: CompileMeter, sizes: Sizes, seed: int,
                     fcfg: ForecasterConfig, series: np.ndarray,
                     tpu: jax.Device):
     """The first local update on the chip against the same computation in
-    f32 on the host CPU backend."""
+    f32 on the host CPU backend.  On the chip the one-layer LSTM is
+    differentiated by the fused sequence kernels: its program holds a
+    ``tpu_custom_call``."""
     params0, data, loss = _first_update(sizes, seed, fcfg, series)
     args = (params0,) + data
     cpu = jax.devices("cpu")[0]
-    kw = dict(cfg=fcfg, loss=loss, cell_impl="jnp")
+    kw = dict(cfg=fcfg, loss=loss)
+    hlo = _local_updates.lower(*jax.device_put(args, tpu), **kw).as_text()
+    _check("tpu_custom_call" in hlo,
+           "the LSTM local update lowered without a tpu_custom_call")
 
     c0, t0 = meter.seconds, time.perf_counter()
     on_chip = jax.block_until_ready(
@@ -233,7 +254,9 @@ def phase_reference(meter: CompileMeter, sizes: Sizes, seed: int,
     jax.block_until_ready(_local_updates(*jax.device_put(args, tpu), **kw))
     steady = time.perf_counter() - t1
     compile_s = meter.seconds - c0
-    on_host = _local_updates(*jax.device_put(args, cpu), **kw)
+    with _scan_recurrence():
+        on_host = jax.block_until_ready(
+            _local_updates(*jax.device_put(args, cpu), **kw))
     _check(_on_tpu(on_chip), "reference slice did not run on the TPU")
     d_rel = _rel(_delta(on_chip[0], params0), _delta(on_host[0], params0))
     l_rel = _rel(on_chip[1], on_host[1])
@@ -245,55 +268,8 @@ def phase_reference(meter: CompileMeter, sizes: Sizes, seed: int,
            f"{TOL_BF16}")
     _emit("reference", compile_s, steady, first_call_s=first,
           clients=sizes.ref_clients, steps=sizes.ref_steps,
-          max_rel_diff_delta=d_rel, max_rel_diff_loss=l_rel, tol=TOL_BF16)
-
-
-def phase_pallas(meter: CompileMeter, sizes: Sizes, seed: int,
-                 fcfg: ForecasterConfig, series: np.ndarray, params):
-    """Fused Pallas cells compiled for the chip vs the jnp cells: the first
-    local update's slice, and the trained model's forecast of one full
-    serving bucket."""
-    params0, data, loss = _first_update(sizes, seed, fcfg, series)
-    args = (params0,) + tuple(jnp.asarray(a) for a in data)
-    xb = jnp.asarray(data[0][0, :sizes.max_batch])
-    kw = dict(cfg=fcfg, loss=loss)
-
-    hlo_update = _local_updates.lower(*args, **kw,
-                                      cell_impl="pallas").as_text()
-    hlo_forecast = forecaster.forecast.lower(params, xb, cfg=fcfg,
-                                             cell_impl="pallas").as_text()
-    _check("tpu_custom_call" in hlo_update,
-           "pallas local_update lowered without a tpu_custom_call")
-    _check("tpu_custom_call" in hlo_forecast,
-           "pallas forecast lowered without a tpu_custom_call")
-
-    c0 = meter.seconds
-    outs, steady = {}, 0.0
-    for impl in ("jnp", "pallas"):
-        upd = jax.block_until_ready(_local_updates(*args, **kw,
-                                                   cell_impl=impl))
-        fc = jax.block_until_ready(forecaster.forecast(params, xb, fcfg,
-                                                       impl))
-        t = time.perf_counter()
-        jax.block_until_ready(_local_updates(*args, **kw, cell_impl=impl))
-        jax.block_until_ready(forecaster.forecast(params, xb, fcfg, impl))
-        if impl == "pallas":
-            steady = time.perf_counter() - t
-        outs[impl] = (upd, fc)
-    compile_s = meter.seconds - c0
-    (u_j, f_j), (u_p, f_p) = outs["jnp"], outs["pallas"]
-    d_rel = _rel(_delta(u_p[0], params0), _delta(u_j[0], params0))
-    f_rel = _rel(f_p, f_j)
-    print(json.dumps({"check": "pallas_vs_jnp", "max_rel_diff_delta": d_rel,
-                      "max_rel_diff_forecast": f_rel, "tol": TOL_BF16,
-                      "tpu_custom_call": True}), flush=True)
-    _check(d_rel <= TOL_BF16 and f_rel <= TOL_BF16,
-           f"pallas vs jnp: delta {d_rel:.3e}, forecast {f_rel:.3e} > "
-           f"{TOL_BF16}")
-    _emit("pallas", compile_s, steady, clients=sizes.ref_clients,
-          batch=64, forecast_bucket=sizes.max_batch,
-          max_rel_diff_delta=d_rel, max_rel_diff_forecast=f_rel,
-          tol=TOL_BF16, tpu_custom_call=True)
+          max_rel_diff_delta=d_rel, max_rel_diff_loss=l_rel, tol=TOL_BF16,
+          tpu_custom_call=True)
 
 
 def phase_serve(meter: CompileMeter, sizes: Sizes, seed: int,
@@ -462,7 +438,6 @@ def main(argv=None) -> int:
     else:
         series, params = phase_train(meter, sizes, args.seed, fcfg)
         phase_reference(meter, sizes, args.seed, fcfg, series, dev)
-        phase_pallas(meter, sizes, args.seed, fcfg, series, params)
         phase_serve(meter, sizes, args.seed, fcfg, params)
     print(json.dumps({"phase": "compile_cache", "dir": cache_dir,
                       "requests": meter.cache_requests,

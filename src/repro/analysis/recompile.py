@@ -130,8 +130,7 @@ def check_round_hot_path(steps: int = 3):
     def step(i: int):
         # per-round key refresh + traced lr: exactly what the engine does
         out = fedavg.pipeline_round(params, x, y, bidx, w, round_keys[i],
-                                    lr, mu, fcfg, loss, tcfg, "jnp", scfg,
-                                    None)
+                                    lr, mu, fcfg, loss, tcfg, scfg, None)
         return out[0]
 
     report = count_recompiles(step, steps=steps,

@@ -453,8 +453,7 @@ def _maybe_analysis(analysis: bool):
 
 
 def trace_pipeline_round(fcfg, tcfg, scfg=None, acfg=None, mesh=None,
-                         m: Optional[int] = None, cell_impl: str = "jnp",
-                         analysis: bool = True):
+                         m: Optional[int] = None, analysis: bool = True):
     """Trace the REAL round body (vmap or mesh path) to a ClosedJaxpr with
     the taint markers active.
 
@@ -485,7 +484,7 @@ def trace_pipeline_round(fcfg, tcfg, scfg=None, acfg=None, mesh=None,
 
         def entry(params, x, y, bidx, w, keys, rk, lr, mu):
             return body(params, x, y, bidx, w, keys, lr, mu, fcfg, loss,
-                        tcfg, cell_impl, scfg, rk if needs_ctx else None)
+                        tcfg, scfg, rk if needs_ctx else None)
 
         with _maybe_analysis(analysis):
             return jax.make_jaxpr(entry)(params, x, y, bidx, w, keys, rk,
@@ -500,7 +499,7 @@ def trace_pipeline_round(fcfg, tcfg, scfg=None, acfg=None, mesh=None,
     with _maybe_analysis(analysis):
         # fresh (uncached) jitted round: lru_cache bypassed on purpose
         fn = fedavg.make_pipeline_round.__wrapped__(
-            mesh, fcfg, loss, tcfg, acfg, cell_impl, scfg)
+            mesh, fcfg, loss, tcfg, acfg, scfg)
         if needs_ctx:
             return jax.make_jaxpr(fn)(params, x, y, bidx, w, keys, slots,
                                       w, rk, lr, mu)
@@ -508,7 +507,7 @@ def trace_pipeline_round(fcfg, tcfg, scfg=None, acfg=None, mesh=None,
 
 
 def trace_client_deltas(fcfg, tcfg, scfg=None, m: int = 4,
-                        cell_impl: str = "jnp", analysis: bool = True):
+                        analysis: bool = True):
     """Trace the semi-sync dispatch stage (``async_engine.client_deltas``)
     — the boundary there is the function's RETURN (the buffered uploads)."""
     from repro.core import async_engine, losses
@@ -522,7 +521,7 @@ def trace_client_deltas(fcfg, tcfg, scfg=None, m: int = 4,
 
     def entry(params, x, y, bidx, w, keys, rk, lr, mu):
         return body(params, x, y, bidx, keys, lr, mu, fcfg, loss, tcfg,
-                    cell_impl, scfg, rk if needs_ctx else None,
+                    scfg, rk if needs_ctx else None,
                     w if needs_ctx else None, None)
 
     with _maybe_analysis(analysis):
@@ -530,8 +529,8 @@ def trace_client_deltas(fcfg, tcfg, scfg=None, m: int = 4,
                                      mu)
 
 
-def verify_pipeline(topology: str, tcfg, scfg=None, fcfg=None,
-                    cell_impl: str = "jnp") -> TaintReport:
+def verify_pipeline(topology: str, tcfg, scfg=None,
+                    fcfg=None) -> TaintReport:
     """Prove sanitize-before-boundary for one topology x config.
 
     ``topology``: ``"vmap"`` (LocalAggregator — the boundary marker is the
@@ -545,13 +544,12 @@ def verify_pipeline(topology: str, tcfg, scfg=None, fcfg=None,
     fcfg = fcfg or ForecasterConfig(hidden_dim=8)
     req = required_labels(tcfg, scfg)
     if topology == "semi_sync":
-        jx = trace_client_deltas(fcfg, tcfg, scfg, cell_impl=cell_impl)
+        jx = trace_client_deltas(fcfg, tcfg, scfg)
     elif topology == "vmap":
-        jx = trace_pipeline_round(fcfg, tcfg, scfg, cell_impl=cell_impl)
+        jx = trace_pipeline_round(fcfg, tcfg, scfg)
     elif topology == "flat":
         mesh = jax.make_mesh((len(jax.devices()),), ("clients",))
-        jx = trace_pipeline_round(fcfg, tcfg, scfg, mesh=mesh,
-                                  cell_impl=cell_impl)
+        jx = trace_pipeline_round(fcfg, tcfg, scfg, mesh=mesh)
     elif topology == "hier":
         n_dev = len(jax.devices())
         if n_dev % 2:
@@ -560,8 +558,7 @@ def verify_pipeline(topology: str, tcfg, scfg=None, fcfg=None,
         mesh = jax.make_mesh((2, n_dev // 2), ("region", "clients"))
         jx = trace_pipeline_round(
             fcfg, tcfg, scfg, mesh=mesh,
-            acfg=AggregationConfig(kind="hierarchical", n_regions=2),
-            cell_impl=cell_impl)
+            acfg=AggregationConfig(kind="hierarchical", n_regions=2))
     else:
         raise ValueError(f"unknown topology {topology!r} "
                          "(vmap | flat | hier | semi_sync)")

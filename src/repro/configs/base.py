@@ -231,7 +231,7 @@ class ModelSpec(Protocol):
     def init(self, key) -> Any:
         """Initial parameters from a PRNG key."""
 
-    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+    def loss(self, params, batch, loss: Callable):
         """Scalar training loss of one batch."""
 
     def param_template(self) -> Any:
@@ -269,9 +269,9 @@ class ForecasterConfig:
         from repro.models import forecaster
         return forecaster.init_forecaster(key, self)
 
-    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+    def loss(self, params, batch, loss: Callable):
         from repro.models import forecaster
-        return forecaster.loss_fn(params, batch, self, loss, cell_impl)
+        return forecaster.loss_fn(params, batch, self, loss)
 
     def param_template(self):
         from repro.models import forecaster
@@ -341,7 +341,7 @@ class HybridForecasterConfig:
         from repro.models import hybrid_forecaster
         return hybrid_forecaster.init(key, self)
 
-    def loss(self, params, batch, loss: Callable, cell_impl: str = "jnp"):
+    def loss(self, params, batch, loss: Callable):
         from repro.models import hybrid_forecaster
         return hybrid_forecaster.loss_fn(params, batch, self, loss)
 

@@ -9,12 +9,9 @@ from repro.core import (aggregation, clustering, fedavg, local_sgd, losses,
 from repro.core.aggregation import (Aggregator, FlatAggregator,
                                     HierarchicalAggregator, LocalAggregator,
                                     make_aggregator)
-from repro.core.fedavg import (FLResult, RoundEngine, engine_round,
-                               evaluate_global, evaluate_unseen_clients,
-                               fedavg_aggregate, fedavg_round,
-                               make_pipeline_round, make_sharded_engine_round,
-                               make_sharded_round, pipeline_round,
-                               run_federated_training, weighted_aggregate)
+from repro.core.fedavg import (FLResult, RoundEngine, evaluate_global,
+                               evaluate_unseen_clients, make_pipeline_round,
+                               pipeline_round, run_federated_training)
 from repro.core.transforms import (DeltaTransform, GaussianNoise, L2Clip,
                                    StochasticQuantize, TransformStack,
                                    make_stack)
@@ -32,11 +29,9 @@ __all__ = ["aggregation", "clustering", "fedavg", "local_sgd", "losses",
            "LocalAggregator", "make_aggregator",
            "DeltaTransform", "GaussianNoise", "L2Clip", "StochasticQuantize",
            "TransformStack", "make_stack",
-           "FLResult", "RoundEngine", "engine_round", "evaluate_global",
-           "evaluate_unseen_clients", "fedavg_aggregate", "fedavg_round",
-           "make_pipeline_round", "make_sharded_engine_round",
-           "make_sharded_round", "pipeline_round",
-           "run_federated_training", "weighted_aggregate", "LocalSGDConfig",
+           "FLResult", "RoundEngine", "evaluate_global",
+           "evaluate_unseen_clients", "make_pipeline_round", "pipeline_round",
+           "run_federated_training", "LocalSGDConfig",
            "OuterState", "fedavg_outer", "init_outer_state", "outer_step",
            "accuracy", "ew_mse", "make_loss", "mape", "mse",
            "per_horizon_accuracy", "rmse", "weighted_ce",

@@ -91,12 +91,10 @@ def staleness_discount(tau, alpha: float):
 
 # ------------------------------------------------------------ client stage
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "loss", "tcfg", "cell_impl",
-                                    "scfg"))
+                   static_argnames=("cfg", "loss", "tcfg", "scfg"))
 def client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
                   cfg: ForecasterConfig, loss: Callable,
                   tcfg: TransformConfig = TransformConfig(),
-                  cell_impl: str = "jnp",
                   scfg: "SecureAggConfig" = None, round_key=None,
                   w_full=None, slots=None):
     """Local-update + transform stages alone: per-client TRANSFORMED deltas
@@ -118,8 +116,8 @@ def client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
     """
     from repro.core import fedavg as fedavg_mod
     locals_, client_loss = jax.vmap(
-        local_update, in_axes=(None, 0, 0, 0, None, None, None, None, None))(
-        params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
+        local_update, in_axes=(None, 0, 0, 0, None, None, None, None))(
+        params, x, y, batch_idx, lr, cfg, loss, prox_mu)
     # taint source (production no-op); the returned deltas ARE the uploads
     # the server's straggler buffer holds, so the exit of this function is
     # the shard boundary flcheck checks on the semi-sync fold path
@@ -136,7 +134,6 @@ def client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
 def make_sharded_client_deltas(mesh, cfg: ForecasterConfig, loss: Callable,
                                tcfg: TransformConfig = TransformConfig(),
                                acfg: AggregationConfig = AggregationConfig(),
-                               cell_impl: str = "jnp",
                                scfg: "SecureAggConfig" = None):
     """Mesh-sharded client stage: same layout as the fused pipeline round
     (clients over the 1-D axis, or the 2-D (region, clients) grid), but the
@@ -158,7 +155,7 @@ def make_sharded_client_deltas(mesh, cfg: ForecasterConfig, loss: Callable,
     if not needs_ctx:
         def body(params, x, y, batch_idx, keys, lr, prox_mu):
             return client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
-                                 cfg, loss, tcfg, cell_impl)
+                                 cfg, loss, tcfg)
 
         return jax.jit(jax.shard_map(
             body, mesh=mesh,
@@ -169,7 +166,7 @@ def make_sharded_client_deltas(mesh, cfg: ForecasterConfig, loss: Callable,
     def secure_body(params, x, y, batch_idx, keys, slots, w_full, round_key,
                     lr, prox_mu):
         return client_deltas(params, x, y, batch_idx, keys, lr, prox_mu,
-                             cfg, loss, tcfg, cell_impl, scfg, round_key,
+                             cfg, loss, tcfg, scfg, round_key,
                              w_full, slots)
 
     return jax.jit(jax.shard_map(
@@ -549,8 +546,7 @@ def semi_sync_step(engine, params, state, x, y, batch_idx, weights,
               if engine.needs_ctx else None)
         deltas, closs = client_deltas(params, x, y, batch_idx, keys, lr, mu,
                                       engine.fcfg, engine.loss,
-                                      engine.transform, engine.cell_impl,
-                                      engine.secure, rk,
+                                      engine.transform, engine.secure, rk,
                                       jnp.asarray(base_w))
     deltas = jax.device_get(deltas)
     closs = np.asarray(closs)

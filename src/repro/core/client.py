@@ -7,7 +7,9 @@ expressed as a fixed-shape ``lax.scan`` over precomputed minibatch indices
 so that the whole client population can be vmapped / shard_mapped over the
 ``clients`` axis — the TPU-native realization of "clients train in
 parallel".  The model is a ``configs.base.ModelSpec`` (its init, loss and
-batch layout), so every model the engine trains shares this stage.  The
+batch layout), so every model the engine trains shares this stage; how
+the model differentiates its recurrence is the model's own choice
+(``models/forecaster.py::fused_recurrence``), not this stage's.  The
 stage's schedule knobs (lr, E, K, B, loss, prox_mu) are carried by the
 typed ``configs.base.ClientOptConfig`` (the ``FLConfig.client_opt`` facade
 view); the traced per-round values (lr, prox_mu) arrive as arguments so one
@@ -34,9 +36,9 @@ PREWINDOW_MAX_WIDTH = 64
 
 
 def sgd_step(params, batch, lr, spec: ModelSpec, loss: Callable,
-             cell_impl: str = "jnp", anchor=None, prox_mu=0.0):
+             anchor=None, prox_mu=0.0):
     """One SGD step; ``anchor``/``prox_mu`` add the FedProx proximal gradient."""
-    l, g = jax.value_and_grad(spec.loss)(params, batch, loss, cell_impl)
+    l, g = jax.value_and_grad(spec.loss)(params, batch, loss)
     if anchor is not None:
         g = jax.tree.map(lambda gw, w, a: gw + prox_mu * (w - a),
                          g, params, anchor)
@@ -84,9 +86,9 @@ def minibatches(x, y, spec: ModelSpec) -> Callable:
     return lambda idx: spec.batch(win[idx])
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
+@functools.partial(jax.jit, static_argnames=("cfg", "loss"))
 def local_update(params, x, y, batch_idx, lr, cfg: ModelSpec,
-                 loss: Callable, cell_impl: str = "jnp", prox_mu=0.0):
+                 loss: Callable, prox_mu=0.0):
     """Run the client's local schedule.
 
     params: global model (pytree); cfg: the model's spec; x: (n_win, L, 1)
@@ -98,8 +100,8 @@ def local_update(params, x, y, batch_idx, lr, cfg: ModelSpec,
     take = minibatches(x, y, cfg)
 
     def step(p, idx):
-        return sgd_step(p, take(idx), lr, cfg, loss, cell_impl,
-                        anchor=anchor, prox_mu=prox_mu)
+        return sgd_step(p, take(idx), lr, cfg, loss, anchor=anchor,
+                        prox_mu=prox_mu)
 
     params, losses = jax.lax.scan(step, params, batch_idx)
     return params, jnp.mean(losses)

@@ -19,13 +19,17 @@ the server applies a *server optimizer* to the pseudo-gradient
 ``w_global - w_agg`` (``core/server_opt.py``, ``ServerOptConfig``) outside
 the round body, shared bit-for-bit by both execution paths.
 
+A round is built in one place, :func:`_pipeline_body`: :func:`pipeline_round`
+runs it under vmap, :func:`make_pipeline_round` inside a shard_map body.
 Uniform FedAvg (``w <- (1/|s|) Σ w_i``) is the default configuration of that
-pipeline, not a special code path — and with the identity transform stack the
-engine routes through the exact legacy aggregation math, so default-config
-runs are bit-identical to the pre-pipeline engine (pinned by regression
-test).  Local epochs run with NO cross-client communication, which is
-precisely what makes FedAvg cheaper on the wire than synchronous
-data-parallel SGD.
+pipeline, not a special code path.  With the identity transform stack the
+round aggregates the raw local models as the weighted mean
+``Σ w_i local_i / Σ w_i``, and ``tests/test_pipeline_api.py`` pins it: a
+default-config run's loss history matches the plain reference
+``bench/references/forecaster.py::fedavg_rounds``, and the vmap path equals
+the mesh path bit for bit.  Local epochs run with NO cross-client
+communication, which is precisely what makes FedAvg cheaper on the wire
+than synchronous data-parallel SGD.
 
 Engine selection is driven entirely by the ``FLConfig`` facade::
 
@@ -73,11 +77,6 @@ from repro.models import forecaster
 
 
 # ------------------------------------------------------------- aggregation
-def fedavg_aggregate(stacked_params):
-    """Uniformly average a client-stacked param tree (leading axis = clients)."""
-    return jax.tree.map(lambda w: jnp.mean(w, axis=0), stacked_params)
-
-
 def _weighted_sums(stacked_params, weights):
     """Per-shard weighted sums: the ONE place the weighting math lives.
 
@@ -92,109 +91,6 @@ def _weighted_sums(stacked_params, weights):
         return jnp.sum(w * wt, axis=0)
 
     return jax.tree.map(ws, stacked_params), jnp.sum(weights)
-
-
-def weighted_aggregate(stacked_params, weights):
-    """Weighted average of a client-stacked tree; weights: (M,) float."""
-    sums, wsum = _weighted_sums(stacked_params, weights)
-    return jax.tree.map(lambda s: s / wsum, sums)
-
-
-# ------------------------------------------------------------ vmap execution
-@functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
-def fedavg_round(params, x, y, batch_idx, lr, cfg: ModelSpec,
-                 loss: Callable, cell_impl: str = "jnp"):
-    """One uniform-FedAvg round over M clients (pseudo-distributed, back-compat).
-
-    x: (M, n_win, L, 1); y: (M, n_win, H); batch_idx: (M, steps, B).
-    """
-    locals_, client_loss = jax.vmap(
-        local_update, in_axes=(None, 0, 0, 0, None, None, None, None))(
-        params, x, y, batch_idx, lr, cfg, loss, cell_impl)
-    return fedavg_aggregate(locals_), jnp.mean(client_loss)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "loss", "cell_impl"))
-def engine_round(params, x, y, batch_idx, weights, lr, prox_mu,
-                 cfg: ModelSpec, loss: Callable,
-                 cell_impl: str = "jnp"):
-    """Generalized round: weighted aggregation + optional FedProx clients.
-
-    weights: (M,) aggregation weights (sample counts; pass ones for uniform);
-    prox_mu: FedProx proximal strength (0 = plain local SGD).  Returns
-    ``(w_agg, weighted mean client loss)`` — the server step is applied by
-    the caller (``RoundEngine.step``).
-    """
-    locals_, client_loss = jax.vmap(
-        local_update, in_axes=(None, 0, 0, 0, None, None, None, None, None))(
-        params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
-    w_agg = weighted_aggregate(locals_, weights)
-    loss_mean = jnp.sum(weights * client_loss) / jnp.sum(weights)
-    return w_agg, loss_mean
-
-
-# ------------------------------------------------------- shard_map execution
-def make_sharded_round(mesh, cfg: ModelSpec, loss: Callable,
-                       client_axis: str = "clients", cell_impl: str = "jnp"):
-    """Uniform-FedAvg round with clients sharded over a mesh axis (back-compat).
-
-    ``round_fn(params, x, y, batch_idx, lr)`` — see
-    :func:`make_sharded_engine_round` for the weighted / FedProx variant.
-    """
-    def round_body(params, x, y, batch_idx, lr):
-        locals_, client_loss = jax.vmap(
-            local_update, in_axes=(None, 0, 0, 0, None, None, None, None))(
-            params, x, y, batch_idx, lr, cfg, loss, cell_impl)
-        summed = jax.tree.map(
-            lambda w: jax.lax.psum(jnp.sum(w, axis=0), client_axis), locals_)
-        n = jax.lax.psum(x.shape[0], client_axis)
-        new_params = jax.tree.map(lambda w: w / n, summed)
-        loss_mean = jax.lax.psum(jnp.sum(client_loss), client_axis) / n
-        return new_params, loss_mean
-
-    pspec = P(client_axis)
-    return jax.jit(jax.shard_map(
-        round_body, mesh=mesh,
-        in_specs=(P(), pspec, pspec, pspec, P()),
-        out_specs=(P(), P()),
-        check_vma=False))
-
-
-@functools.lru_cache(maxsize=None)
-def make_sharded_engine_round(mesh, cfg: ModelSpec, loss: Callable,
-                              client_axis: str = "clients",
-                              cell_impl: str = "jnp"):
-    """Generalized sharded round; aggregation stays ONE psum of the param tree.
-
-    lru_cached on (mesh, cfg, loss, ...) so every engine configuration with
-    the same execution geometry shares one jitted round — the server
-    optimizer lives outside the round body and costs no recompile.
-
-    ``round_fn(params, x, y, batch_idx, weights, lr, prox_mu)`` with the
-    client-stacked args (x, y, batch_idx, weights) sharded over
-    ``client_axis``.  Each shard locally weight-sums its clients' params, the
-    cross-shard reduction is a single ``psum``, and the weight normalizer is
-    one scalar ``psum`` — identical math to :func:`engine_round`.
-    """
-    def round_body(params, x, y, batch_idx, weights, lr, prox_mu):
-        locals_, client_loss = jax.vmap(
-            local_update,
-            in_axes=(None, 0, 0, 0, None, None, None, None, None))(
-            params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
-        sums, wsum_local = _weighted_sums(locals_, weights)
-        wsum = jax.lax.psum(wsum_local, client_axis)
-        w_agg = jax.tree.map(
-            lambda s: jax.lax.psum(s, client_axis) / wsum, sums)
-        loss_mean = jax.lax.psum(jnp.sum(weights * client_loss),
-                                 client_axis) / wsum
-        return w_agg, loss_mean
-
-    pspec = P(client_axis)
-    return jax.jit(jax.shard_map(
-        round_body, mesh=mesh,
-        in_specs=(P(), pspec, pspec, pspec, pspec, P(), P()),
-        out_specs=(P(), P()),
-        check_vma=False))
 
 
 # ------------------------------------------------------- pipeline execution
@@ -227,8 +123,8 @@ def apply_stack(stack, deltas, keys, *, slots=None, w_full=None,
 
 
 def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
-                   cfg: ModelSpec, loss: Callable, cell_impl: str,
-                   tcfg: TransformConfig, agg: "aggregation_mod.Aggregator",
+                   cfg: ModelSpec, loss: Callable, tcfg: TransformConfig,
+                   agg: "aggregation_mod.Aggregator",
                    scfg: Optional[SecureAggConfig] = None, round_key=None,
                    slots=None, w_full=None):
     """Shared local-update -> transform -> aggregate stages of one round.
@@ -236,10 +132,10 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     Runs inside vmap (``agg = LocalAggregator``) or inside the shard_map body
     (``agg`` = flat / hierarchical), so both execution paths and every
     topology share ONE implementation of the stage math.  With the identity
-    transform stack the raw local models are aggregated through exactly the
-    legacy ops (bit-identical to the pre-pipeline engine); with transforms
-    the per-client deltas are transformed BEFORE the collective and the
-    aggregate is rebuilt as ``w_global + avg(transformed deltas)``.  With
+    transform stack the raw local models are aggregated as their weighted
+    mean (:func:`_weighted_sums`); with transforms the per-client deltas
+    are transformed BEFORE the collective and the aggregate is rebuilt as
+    ``w_global + avg(transformed deltas)``.  With
     secure aggregation the stack is cohort-aware: the extra
     ``round_key`` / ``slots`` / ``w_full`` args feed the pairwise masker,
     whose masks cancel in ``agg.reduce`` (a linear sum — the aggregator
@@ -259,17 +155,17 @@ def _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
     w_cohort = weights if w_full is None else w_full
     if client_loop(params, x.shape[0]) == "scan":
         return _scan_round(params, x, y, batch_idx, weights, keys, lr,
-                           prox_mu, cfg=cfg, loss=loss, cell_impl=cell_impl,
-                           stack=stack, agg=agg, slots=slots,
-                           w_cohort=w_cohort, round_key=round_key)
+                           prox_mu, cfg=cfg, loss=loss, stack=stack, agg=agg,
+                           slots=slots, w_cohort=w_cohort,
+                           round_key=round_key)
     # named scopes put the stage in each op's HLO ``op_name`` metadata
     # (``.../local_update/...``), so a device trace attributes op time to
     # its stage; they add no primitive
     with jax.named_scope("local_update"):
         locals_, client_loss = jax.vmap(
             local_update,
-            in_axes=(None, 0, 0, 0, None, None, None, None, None))(
-            params, x, y, batch_idx, lr, cfg, loss, cell_impl, prox_mu)
+            in_axes=(None, 0, 0, 0, None, None, None, None))(
+            params, x, y, batch_idx, lr, cfg, loss, prox_mu)
     # taint source (production no-op): per-client local models — and the
     # deltas derived from them — are the private values flcheck tracks to
     # the aggregation boundary.  client_loss is deliberately NOT tagged:
@@ -319,7 +215,7 @@ def _aggregate_deltas(params, sums, wsum, stack, agg, w_cohort):
 
 
 def _scan_round(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
-                cfg: ModelSpec, loss: Callable, cell_impl: str, stack, agg,
+                cfg: ModelSpec, loss: Callable, stack, agg,
                 slots, w_cohort, round_key):
     """The round with its clients one after another (:func:`client_loop`).
 
@@ -341,7 +237,7 @@ def _scan_round(params, x, y, batch_idx, weights, keys, lr, prox_mu, *,
         xi, yi, bi, wi, ki, si = inp
         with jax.named_scope("local_update"):
             local, l = local_update(params, xi, yi, bi, lr, cfg, loss,
-                                    cell_impl, prox_mu)
+                                    prox_mu)
         local = taint_mod.tag_private(local)       # as on the vmap path
         if stack.is_identity:
             term = jax.tree.map(lambda a: a * (wi / wsum), local)
@@ -400,11 +296,9 @@ def client_loop(params, m: int) -> str:
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "loss", "tcfg", "cell_impl",
-                                    "scfg"))
+                   static_argnames=("cfg", "loss", "tcfg", "scfg"))
 def pipeline_round(params, x, y, batch_idx, weights, keys, lr, prox_mu,
-                   cfg: ModelSpec, loss: Callable,
-                   tcfg: TransformConfig, cell_impl: str = "jnp",
+                   cfg: ModelSpec, loss: Callable, tcfg: TransformConfig,
                    scfg: Optional[SecureAggConfig] = None, round_key=None):
     """Full pipeline round, pseudo-distributed (vmap) execution.
 
@@ -416,7 +310,7 @@ def pipeline_round(params, x, y, batch_idx, weights, keys, lr, prox_mu,
     the caller (``RoundEngine.step``).
     """
     return _pipeline_body(params, x, y, batch_idx, weights, keys, lr, prox_mu,
-                          cfg=cfg, loss=loss, cell_impl=cell_impl, tcfg=tcfg,
+                          cfg=cfg, loss=loss, tcfg=tcfg,
                           agg=aggregation_mod.LocalAggregator(), scfg=scfg,
                           round_key=round_key)
 
@@ -425,7 +319,6 @@ def pipeline_round(params, x, y, batch_idx, weights, keys, lr, prox_mu,
 def make_pipeline_round(mesh, cfg: ModelSpec, loss: Callable,
                         tcfg: TransformConfig = TransformConfig(),
                         acfg: AggregationConfig = AggregationConfig(),
-                        cell_impl: str = "jnp",
                         scfg: Optional[SecureAggConfig] = None):
     """Mesh-sharded pipeline round for any aggregation topology.
 
@@ -456,8 +349,8 @@ def make_pipeline_round(mesh, cfg: ModelSpec, loss: Callable,
     if not needs_ctx:
         def round_body(params, x, y, batch_idx, weights, keys, lr, prox_mu):
             return _pipeline_body(params, x, y, batch_idx, weights, keys, lr,
-                                  prox_mu, cfg=cfg, loss=loss,
-                                  cell_impl=cell_impl, tcfg=tcfg, agg=agg)
+                                  prox_mu, cfg=cfg, loss=loss, tcfg=tcfg,
+                                  agg=agg)
 
         return jax.jit(jax.shard_map(
             round_body, mesh=mesh,
@@ -468,10 +361,9 @@ def make_pipeline_round(mesh, cfg: ModelSpec, loss: Callable,
     def secure_body(params, x, y, batch_idx, weights, keys, slots, w_full,
                     round_key, lr, prox_mu):
         return _pipeline_body(params, x, y, batch_idx, weights, keys, lr,
-                              prox_mu, cfg=cfg, loss=loss,
-                              cell_impl=cell_impl, tcfg=tcfg, agg=agg,
-                              scfg=scfg, round_key=round_key, slots=slots,
-                              w_full=w_full)
+                              prox_mu, cfg=cfg, loss=loss, tcfg=tcfg,
+                              agg=agg, scfg=scfg, round_key=round_key,
+                              slots=slots, w_full=w_full)
 
     return jax.jit(jax.shard_map(
         secure_body, mesh=mesh,
@@ -503,14 +395,13 @@ class RoundEngine:
 
     def __init__(self, fcfg: ModelSpec, flcfg: FLConfig, *,
                  loss: Optional[Callable] = None, mesh=None,
-                 cell_impl: str = "jnp",
                  audited_payload: Optional[float] = None):
         # stage names/knobs were validated eagerly by the FLConfig facade
         self.fcfg, self.flcfg = fcfg, flcfg
         ccfg = flcfg.client_opt
         self.loss = loss if loss is not None else losses_mod.make_loss(
             ccfg.loss, ccfg.beta)
-        self.mesh, self.cell_impl = mesh, cell_impl
+        self.mesh = mesh
         self.sampler = sampling_mod.make_sampler(flcfg.sampling_config)
         # proximal term only under fedprox (prox_mu is ignored otherwise)
         self.prox_mu = ccfg.prox_mu if flcfg.server_opt == "fedprox" else 0.0
@@ -535,8 +426,7 @@ class RoundEngine:
         else:
             self._sharded = make_pipeline_round(
                 mesh, fcfg, self.loss, self.transform,
-                flcfg.aggregation_config, cell_impl=cell_impl,
-                scfg=self.secure)
+                flcfg.aggregation_config, scfg=self.secure)
             self._client_sharding = NamedSharding(
                 mesh, aggregation_mod.make_aggregator(
                     flcfg.aggregation_config, mesh).pspec())
@@ -573,8 +463,7 @@ class RoundEngine:
             if mesh is not None:
                 self._client_fn = async_engine.make_sharded_client_deltas(
                     mesh, fcfg, self.loss, flcfg.transform,
-                    flcfg.aggregation_config, cell_impl=cell_impl,
-                    scfg=self.secure)
+                    flcfg.aggregation_config, scfg=self.secure)
         else:
             self.buffer_k = 0
 
@@ -760,8 +649,7 @@ class RoundEngine:
         else:
             w_agg, loss = pipeline_round(params, x, y, batch_idx, w, keys,
                                          lr, mu, self.fcfg, self.loss,
-                                         self.transform, self.cell_impl,
-                                         self.secure, rk)
+                                         self.transform, self.secure, rk)
         params, state = server_opt_mod.server_update(params, w_agg, state,
                                                      self.flcfg.server)
         return params, state, loss
@@ -1043,8 +931,7 @@ def run_federated_training(all_series, fcfg: ModelSpec,
         # whether the fused kernels or the scan differentiate the LSTM
         loop = client_loop(params, m_run // n_dev)
         tokens = m_run * steps * ccfg.batch_size * fcfg.lookback
-        recurrence = ("fused" if forecaster.fused_recurrence(
-            fcfg, engine.cell_impl) else "scan")
+        recurrence = "fused" if forecaster.fused_recurrence(fcfg) else "scan"
         stopped = False
         for t in range(t0, flcfg.rounds):
             # host spans on the profiler's clock (about a microsecond each
@@ -1122,9 +1009,9 @@ def run_federated_training(all_series, fcfg: ModelSpec,
 
 
 # ------------------------------------------------------------------ eval
-@functools.partial(jax.jit, static_argnames=("cfg", "cell_impl"))
-def _predict(params, x, cfg, cell_impl="jnp"):
-    return forecaster.forecast(params, x, cfg, cell_impl)
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _predict(params, x, cfg):
+    return forecaster.forecast(params, x, cfg)
 
 
 class MetricAccumulator:

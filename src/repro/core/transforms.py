@@ -57,10 +57,9 @@ Knob -> literature map (see PAPERS.md):
 
 Transforms compose as a :class:`TransformStack` in the fixed order
 clip -> noise -> quantize -> mask (sensitivity bound first, privacy second,
-compression third, wire masking last).  The empty stack is the identity and
-keeps the round bit-identical to the pre-transform engine
-(``core/fedavg.py`` routes identity stacks through the legacy aggregation
-math).
+compression third, wire masking last).  The empty stack is the identity:
+``core/fedavg.py`` then aggregates the raw local models as their weighted
+mean, with no delta taken.
 """
 from __future__ import annotations
 

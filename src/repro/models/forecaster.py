@@ -4,17 +4,12 @@ Univariate input: a look-back window of L normalized kWh readings, shape
 (B, L, input_dim); output: (B, horizon) — multi-step direct forecast, matching
 the paper's 8-step look-back / 4-step (1 h) horizon.
 
-The recurrent cells are written so the per-step compute is one fused function
-of ``(x_t, state, params)``; ``cell_impl="jnp"`` uses the pure-jnp path (the
-oracle), ``cell_impl="pallas"`` routes through the fused Pallas TPU cell in
-``repro.kernels`` — compiled on a TPU, interpreted on other backends, as the
-platform decides when the forward is traced.
-
-Training a one-layer LSTM on a TPU differentiates its whole look-back
-through the fused sequence kernels (``kernels/lstm_seq.py``): forward and
-backward keep the gates, h and c in VMEM.  :func:`fused_recurrence`
-decides it from the spec, the implementation and the platform; the value
-the forward returns is the scan's either way.
+Each layer is a ``lax.scan`` of its jnp cell over the look-back, with one
+exception: training a one-layer LSTM on a TPU differentiates its whole
+look-back through the fused sequence kernels (``kernels/lstm_seq.py``):
+forward and backward keep the gates, h and c in VMEM.
+:func:`fused_recurrence` decides it from the spec and the platform; the
+value the forward returns is the scan's either way.
 """
 from __future__ import annotations
 
@@ -91,27 +86,20 @@ def gru_cell(x_t, h, p):
     return z * h + (1.0 - z) * h_tilde
 
 
-def _pallas_cells():
-    from repro.kernels import ops as kops
-    return kops.lstm_cell_fused, kops.gru_cell_fused
-
-
 # ------------------------------------------------------------------ forward
-def fused_recurrence(cfg, cell_impl: str) -> bool:
+def fused_recurrence(cfg) -> bool:
     """Whether the LSTM layer is differentiated by the fused sequence
-    kernels: on a TPU, for a one-layer LSTM forecaster on the jnp cells,
-    at widths the kernels' layout holds (``lstm_seq.fits``).  Every other
-    case (the GRU, deeper stacks, ``cell_impl="pallas"``, another model
-    spec, another backend) runs the scan.  Read at trace time, once per
-    compiled round."""
+    kernels: on a TPU, for a one-layer LSTM forecaster, at widths the
+    kernels' layout holds (``lstm_seq.fits``).  Every other case (the GRU,
+    deeper stacks, another model spec, another backend) runs the scan.
+    Read at trace time, once per compiled round."""
     from repro.kernels import lstm_seq, platform
     return (isinstance(cfg, ForecasterConfig) and cfg.cell == "lstm"
-            and cfg.n_layers == 1 and cell_impl == "jnp"
-            and platform.on_tpu()
+            and cfg.n_layers == 1 and platform.on_tpu()
             and lstm_seq.fits(cfg.hidden_dim, cfg.lookback, cfg.input_dim))
 
 
-def _layer(h_seq, p, cell: str, lstm_step, gru_step):
+def _layer(h_seq, p, cell: str):
     """One recurrent layer over the sequence: (B, L, in) -> (B, L, H)."""
     B = h_seq.shape[0]
     H = p["wh"].shape[0]
@@ -119,12 +107,12 @@ def _layer(h_seq, p, cell: str, lstm_step, gru_step):
     if cell == "lstm":
         def step(carry, x_t):
             h, c = carry
-            h, c = lstm_step(x_t, h, c, p)
+            h, c = lstm_cell(x_t, h, c, p)
             return (h, c), h
         init = (jnp.zeros((B, H), dtype), jnp.zeros((B, H), dtype))
     else:
         def step(carry, x_t):
-            h = gru_step(x_t, carry[0], p)
+            h = gru_cell(x_t, carry[0], p)
             return (h, carry[1]), h
         init = (jnp.zeros((B, H), dtype), jnp.zeros((B, 0), dtype))
     (_, _), hs = jax.lax.scan(step, init, h_seq.swapaxes(0, 1))
@@ -136,7 +124,7 @@ def _lstm_last_h(x, wx, wh, b):
     """The LSTM layer's last hidden state: the scan forward, the fused
     kernels under differentiation."""
     p = {"wx": wx, "wh": wh, "b": b}
-    return _layer(x, p, "lstm", lstm_cell, gru_cell)[:, -1]
+    return _layer(x, p, "lstm")[:, -1]
 
 
 def _lstm_last_h_fwd(x, wx, wh, b):
@@ -154,25 +142,21 @@ def _lstm_last_h_bwd(res, dh):
 _lstm_last_h.defvjp(_lstm_last_h_fwd, _lstm_last_h_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "cell_impl"))
-def forecast(params, x, cfg: ForecasterConfig, cell_impl: str = "jnp"):
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def forecast(params, x, cfg: ForecasterConfig):
     """x: (B, L, input_dim) -> (B, horizon)."""
-    if fused_recurrence(cfg, cell_impl):
+    if fused_recurrence(cfg):
         p = params["layers"][0]
         h_last = _lstm_last_h(x, p["wx"], p["wh"], p["b"])
     else:
-        if cell_impl == "pallas":
-            lstm_step, gru_step = _pallas_cells()
-        else:
-            lstm_step, gru_step = lstm_cell, gru_cell
         h_seq = x
         for p in params["layers"]:
-            h_seq = _layer(h_seq, p, cfg.cell, lstm_step, gru_step)
+            h_seq = _layer(h_seq, p, cfg.cell)
         h_last = h_seq[:, -1]
     return h_last @ params["head"]["w"] + params["head"]["b"]
 
 
-def loss_fn(params, batch, cfg: ForecasterConfig, loss, cell_impl="jnp"):
+def loss_fn(params, batch, cfg: ForecasterConfig, loss):
     """batch: {"x": (B,L,1), "y": (B,horizon)} -> scalar loss."""
-    pred = forecast(params, batch["x"], cfg, cell_impl)
+    pred = forecast(params, batch["x"], cfg)
     return loss(pred, batch["y"])

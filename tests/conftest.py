@@ -8,6 +8,9 @@ environment: plain ``pytest`` runs single-device, while ``./test.sh``
 exports ``--xla_force_host_platform_device_count=8`` up front so the
 multi-device shard_map tests run on real (virtual) meshes.
 """
+import importlib.util
+import pathlib
+
 import jax
 
 jax.devices()                                            # lock backend now
@@ -15,7 +18,35 @@ jax.devices()                                            # lock backend now
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
 
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def reference_losses():
+    """``losses(series, fcfg, flcfg)``: the round losses of the plain
+    FedAvg reference, ``bench/references/forecaster.py::fedavg_rounds``
+    (loaded from its file; it imports nothing of the system), for a
+    ``run_federated_training`` run of plain FedAvg: no clustering, no
+    holdout, uniform weights."""
+    spec = importlib.util.spec_from_file_location(
+        "reference_forecaster", ROOT / "bench/references/forecaster.py")
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    def losses(series, fcfg, flcfg):
+        cfg = {k: getattr(fcfg, k) for k in (
+            "cell", "input_dim", "hidden_dim", "n_layers", "lookback",
+            "horizon")}
+        fl = {"clients_per_round": flcfg.clients_per_round,
+              "batch_size": flcfg.batch_size, "lr": flcfg.lr,
+              "beta": flcfg.beta,
+              "train_frac": 0.75}          # the window provider's split
+        _, out = ref.fedavg_rounds(np.asarray(series), flcfg.seed, cfg, fl,
+                                   flcfg.rounds)
+        return np.asarray(out, np.float64)
+    return losses

@@ -16,13 +16,9 @@ from repro.data import synthetic
 
 FCFG = ForecasterConfig(cell="lstm", hidden_dim=8)
 
-# same golden workload + constants as tests/test_pipeline_api.py — vmap and
-# shard_map pins re-captured for the fold_in engine-init key (the two paths
-# differ by one f32 ulp of summation order on these values; see the
-# GOLDEN comment in tests/test_pipeline_api.py)
-GOLDEN = [0.12595632672309875, 0.055874377489089966, 0.04063640534877777]
-GOLDEN_SHARD = [0.12595631182193756, 0.055874377489089966,
-                0.04063640907406807]
+# the golden workload of tests/test_pipeline_api.py: a sync run matches the
+# plain FedAvg reference (``reference_losses``) to this tolerance
+REF_RTOL = 1e-5
 
 
 def _workload(**kw):
@@ -89,7 +85,9 @@ def test_staleness_discount_monotone_and_alpha0(alpha, tau, dtau):
     d2 = float(async_engine.staleness_discount(tau + dtau, alpha))
     assert 0.0 < d1 <= 1.0
     assert d2 <= d1
-    if alpha > 0:
+    # the relative drop is alpha * log((1+tau+dtau) / (1+tau)): strict
+    # wherever it is well above float64's resolution (2.2e-16)
+    if alpha * np.log1p(dtau / (1.0 + tau)) > 1e-12:
         assert d2 < d1
     assert async_engine.staleness_discount(tau, 0.0) == 1.0
     assert async_engine.staleness_discount(0, alpha) == 1.0
@@ -138,13 +136,14 @@ def test_payload_bytes_and_link_budget():
 
 
 # ------------------------------------------- sync equivalence + golden pin
-def test_sync_mode_golden_loss_pin():
-    """mode="sync" (the default) stays bit-identical to the pre-async
-    engine on the golden workload."""
+def test_sync_mode_golden_loss_pin(reference_losses):
+    """mode="sync" (the default) is plain FedAvg on the golden workload:
+    its loss history matches the plain reference."""
     series, flcfg = _workload(mode="sync")
     res = fedavg.run_federated_training(series, FCFG, flcfg)[-1]
-    np.testing.assert_array_equal(res.loss_history,
-                                  np.asarray(GOLDEN, np.float64))
+    np.testing.assert_allclose(res.loss_history,
+                               reference_losses(series, FCFG, flcfg),
+                               rtol=REF_RTOL)
     assert res.sim_times.shape == (3,)
     assert (np.diff(res.sim_times) > 0).all()   # event clock advances
 
@@ -164,7 +163,8 @@ def test_semi_sync_wait_for_all_zero_jitter_equals_sync_vmap():
 
 @pytest.mark.skipif(len(jax.devices()) < 8,
                     reason="needs 8 virtual devices (run via ./test.sh)")
-def test_semi_sync_wait_for_all_zero_jitter_equals_sync_shard_map():
+def test_semi_sync_wait_for_all_zero_jitter_equals_sync_shard_map(
+        reference_losses):
     series, sync_cfg = _workload()
     _, semi_cfg = _workload(mode="semi_sync")
     mesh = jax.make_mesh((8,), ("clients",))
@@ -174,9 +174,10 @@ def test_semi_sync_wait_for_all_zero_jitter_equals_sync_shard_map():
                                            mesh=mesh)[-1]
     np.testing.assert_array_equal(r_sync.loss_history, r_semi.loss_history)
     jax.tree.map(np.testing.assert_array_equal, r_sync.params, r_semi.params)
-    # and the shard_map semi-sync run equals the shard_map golden pin
-    np.testing.assert_array_equal(r_semi.loss_history,
-                                  np.asarray(GOLDEN_SHARD, np.float64))
+    # and the shard_map semi-sync run matches the plain reference
+    np.testing.assert_allclose(r_semi.loss_history,
+                               reference_losses(series, FCFG, sync_cfg),
+                               rtol=REF_RTOL)
 
 
 # ------------------------------------------------------- buffered path

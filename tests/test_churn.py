@@ -13,10 +13,6 @@ from repro.data import synthetic
 
 FCFG = ForecasterConfig(cell="lstm", hidden_dim=8)
 
-# same golden workload as tests/test_async_engine.py (vmap-path pin,
-# re-captured for the fold_in engine-init key — see tests/test_pipeline_api.py)
-GOLDEN = [0.12595632672309875, 0.055874377489089966, 0.04063640534877777]
-
 
 def _workload(**kw):
     series = synthetic.generate_buildings("CA", list(range(6)), days=20)
@@ -108,11 +104,14 @@ CHURN = dict(mode="semi_sync", over_select=1.5, staleness_alpha=0.5,
 
 def test_churn_off_semi_sync_stays_bit_identical_to_pr5():
     """dropout_prob = 0 must not perturb the event schedule: the buffer_k=m'
-    zero-jitter semi-sync run still reproduces the sync golden pin."""
+    zero-jitter semi-sync run equals the sync run of the same workload,
+    bit for bit."""
     series, flcfg = _workload(mode="semi_sync")
+    _, sync_cfg = _workload()
     res = fedavg.run_federated_training(series, FCFG, flcfg)[-1]
-    np.testing.assert_array_equal(res.loss_history,
-                                  np.asarray(GOLDEN, np.float64))
+    res_sync = fedavg.run_federated_training(series, FCFG, sync_cfg)[-1]
+    np.testing.assert_array_equal(res.loss_history, res_sync.loss_history)
+    jax.tree.map(np.testing.assert_array_equal, res.params, res_sync.params)
 
 
 def test_dropout_run_trains_and_counts_failures(monkeypatch):

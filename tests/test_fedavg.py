@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import FLConfig, ForecasterConfig
+from repro.configs.base import FLConfig, ForecasterConfig, TransformConfig
 from repro.core import fedavg, losses
 from repro.core.client import local_update
 from repro.data import synthetic, windows
@@ -19,10 +19,21 @@ def small_fl():
     return series, fcfg, data
 
 
+def _uniform_round(params, x, y, bidx, lr, fcfg, loss):
+    """One uniform-FedAvg round: the pipeline with equal weights and the
+    identity transform stack."""
+    m = x.shape[0]
+    return fedavg.pipeline_round(
+        params, x, y, bidx, jnp.ones((m,), jnp.float32),
+        jnp.zeros((m, 2), jnp.uint32), lr, 0.0, fcfg, loss,
+        TransformConfig())
+
+
 def test_aggregate_is_mean():
     trees = {"a": jnp.arange(12.0).reshape(3, 4),
              "b": [jnp.ones((3, 2)), jnp.zeros((3,))]}
-    agg = fedavg.fedavg_aggregate(trees)
+    sums, wsum = fedavg._weighted_sums(trees, jnp.ones((3,)))
+    agg = jax.tree.map(lambda s: s / wsum, sums)
     np.testing.assert_allclose(agg["a"], jnp.arange(12.0).reshape(3, 4)
                                .mean(0))
     np.testing.assert_allclose(agg["b"][0], 1.0)
@@ -37,7 +48,7 @@ def test_single_client_round_equals_local_sgd(small_fl):
     y = jnp.asarray(data["y_train"][:1])
     bidx = jnp.asarray(np.random.default_rng(0)
                        .integers(0, x.shape[1], size=(1, 5, 16)))
-    p_fed, _ = fedavg.fedavg_round(params, x, y, bidx, 0.01, fcfg, loss)
+    p_fed, _ = _uniform_round(params, x, y, bidx, 0.01, fcfg, loss)
     p_loc, _ = local_update(params, x[0], y[0], bidx[0], 0.01, fcfg, loss)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, atol=1e-6),
                  p_fed, p_loc)
@@ -53,7 +64,7 @@ def test_round_loss_decreases(small_fl):
     hist = []
     for t in range(8):
         bidx = jnp.asarray(r.integers(0, x.shape[1], size=(6, 10, 32)))
-        params, l = fedavg.fedavg_round(params, x, y, bidx, 0.05, fcfg, loss)
+        params, l = _uniform_round(params, x, y, bidx, 0.05, fcfg, loss)
         hist.append(float(l))
     assert hist[-1] < hist[0]
 
@@ -68,9 +79,11 @@ def test_sharded_round_matches_vmap_round(small_fl):
     y = jnp.asarray(data["y_train"])
     bidx = jnp.asarray(np.random.default_rng(0)
                        .integers(0, x.shape[1], size=(6, 4, 16)))
-    p1, l1 = fedavg.fedavg_round(params, x, y, bidx, 0.05, fcfg, loss)
-    round_fn = fedavg.make_sharded_round(mesh, fcfg, loss)
-    p2, l2 = round_fn(params, x, y, bidx, jnp.float32(0.05))
+    p1, l1 = _uniform_round(params, x, y, bidx, 0.05, fcfg, loss)
+    round_fn = fedavg.make_pipeline_round(mesh, fcfg, loss)
+    p2, l2 = round_fn(params, x, y, bidx, jnp.ones((6,), jnp.float32),
+                      jnp.zeros((6, 2), jnp.uint32), jnp.float32(0.05),
+                      jnp.float32(0.0))
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4,
                                                          atol=1e-6), p1, p2)

@@ -108,20 +108,6 @@ def test_lstm_cell_property(seed, H, B):
     np.testing.assert_allclose(c1, c2, rtol=1e-5, atol=1e-5)
 
 
-def test_forecaster_pallas_path_matches_jnp():
-    """cell_impl='pallas' gives the same forecasts as the jnp path."""
-    from repro.configs.base import ForecasterConfig
-    from repro.models import forecaster
-    r = np.random.default_rng(0)
-    for cell in ("lstm", "gru"):
-        cfg = ForecasterConfig(cell=cell, hidden_dim=32)
-        params = forecaster.init_forecaster(jax.random.PRNGKey(0), cfg)
-        x = jnp.asarray(r.normal(size=(16, cfg.lookback, 1)), jnp.float32)
-        y1 = forecaster.forecast(params, x, cfg, "jnp")
-        y2 = forecaster.forecast(params, x, cfg, "pallas")
-        np.testing.assert_allclose(y1, y2, rtol=1e-5, atol=1e-5)
-
-
 def test_model_flash_path_matches_jnp():
     """USE_FLASH_KERNEL routes full-sequence attention through the Pallas
     kernel (interpret mode) — model outputs must match the jnp path."""

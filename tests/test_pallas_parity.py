@@ -1,11 +1,11 @@
-"""Tier-1 parity smoke for the fused Pallas recurrent cells on the federated
-round path (ROADMAP "Pallas client kernel", first wiring step).
+"""Parity of the fused LSTM sequence kernels on the federated round path.
 
-``local_update`` differentiates through the forecaster, and ``pallas_call``
-has no autodiff rule — ``kernels/ops.py`` closes the gap with a
-``custom_vjp`` (fused forward, reference-VJP backward), which is what these
-tests pin: one full client local-update step with ``cell_impl="pallas"``
-(interpret mode on CPU) must match the pure-jnp oracle path.  Skips cleanly
+``local_update`` differentiates through the forecaster; on a TPU a
+one-layer LSTM's look-back is differentiated by the fused sequence kernels
+(``kernels/lstm_seq.py``, chosen by ``forecaster.fused_recurrence``).
+These tests run the kernels interpreted on the CPU, with the choice
+steered to them, and pin them to value_and_grad of the jnp scan: the
+kernels alone, a client's local update, and a mesh round.  Skips cleanly
 where Pallas is unavailable.
 """
 import functools
@@ -20,44 +20,8 @@ pytest.importorskip("jax.experimental.pallas",
 
 from repro.configs.base import ForecasterConfig
 from repro.core import losses
-from repro.core.client import local_update
 from repro.kernels import lstm_seq, ops, platform
 from repro.models import forecaster
-
-LOSS = losses.make_loss("mse")
-
-
-def _data(rng, n_win=12, lookback=8, horizon=4):
-    x = jnp.asarray(rng.random((n_win, lookback, 1)), jnp.float32)
-    y = jnp.asarray(rng.random((n_win, horizon)), jnp.float32)
-    bidx = jnp.asarray(rng.integers(0, n_win, (2, 8)))
-    return x, y, bidx
-
-
-@pytest.mark.parametrize("cell", ["lstm", "gru"])
-def test_local_update_pallas_matches_jnp(cell):
-    """One ClientUpdate (2 SGD steps) through the fused cell == jnp oracle."""
-    fcfg = ForecasterConfig(cell=cell, hidden_dim=8)
-    params = forecaster.init_forecaster(jax.random.PRNGKey(0), fcfg)
-    x, y, bidx = _data(np.random.default_rng(0))
-    p_jnp, l_jnp = local_update(params, x, y, bidx, 0.05, fcfg, LOSS, "jnp")
-    p_pal, l_pal = local_update(params, x, y, bidx, 0.05, fcfg, LOSS,
-                                "pallas")
-    np.testing.assert_allclose(float(l_jnp), float(l_pal), rtol=1e-5)
-    jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-4,
-                                                         atol=1e-5),
-                 p_jnp, p_pal)
-
-
-def test_forecast_pallas_matches_jnp():
-    """Inference path parity (no grad): fused forward == jnp forward."""
-    fcfg = ForecasterConfig(cell="lstm", hidden_dim=8)
-    params = forecaster.init_forecaster(jax.random.PRNGKey(1), fcfg)
-    x, _, _ = _data(np.random.default_rng(1))
-    f_jnp = forecaster.forecast(params, x, fcfg, "jnp")
-    f_pal = forecaster.forecast(params, x, fcfg, "pallas")
-    np.testing.assert_allclose(np.asarray(f_jnp), np.asarray(f_pal),
-                               rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------- the fused LSTM sequence kernels
@@ -155,7 +119,7 @@ def test_local_update_fused_matches_scan(loop, prox_mu, monkeypatch):
                                        lr=jnp.float32(0.05)))(series, bidx)
 
     p_scan, l_scan = update()
-    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c, i: True)
+    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c: True)
     p_fused, l_fused = update()
     jax.clear_caches()
     np.testing.assert_allclose(np.asarray(l_fused), np.asarray(l_scan),
@@ -163,17 +127,16 @@ def test_local_update_fused_matches_scan(loop, prox_mu, monkeypatch):
     jax.tree.map(_close, p_fused, p_scan)
 
 
-@pytest.mark.parametrize("spec,cell_impl,tpu,want", [
-    ("lstm", "jnp", True, True),
-    ("lstm", "jnp", False, False),           # the CPU
-    ("gru", "jnp", True, False),
-    ("lstm2", "jnp", True, False),           # n_layers == 2
-    ("lstm", "pallas", True, False),
-    ("hybrid", "jnp", True, False),
-    ("lstm32", "jnp", True, False),          # 2H is not whole lane tiles
+@pytest.mark.parametrize("spec,tpu,want", [
+    ("lstm", True, True),
+    ("lstm", False, False),                  # the CPU
+    ("gru", True, False),
+    ("lstm2", True, False),                  # n_layers == 2
+    ("hybrid", True, False),
+    ("lstm32", True, False),                 # 2H is not whole lane tiles
 ])
-def test_fused_recurrence_is_chosen_from_spec_impl_and_platform(
-        spec, cell_impl, tpu, want, monkeypatch):
+def test_fused_recurrence_is_chosen_from_spec_and_platform(
+        spec, tpu, want, monkeypatch):
     from repro.configs.base import HybridForecasterConfig
     cfg = {"lstm": ForecasterConfig(), "gru": ForecasterConfig(cell="gru"),
            "lstm2": ForecasterConfig(n_layers=2),
@@ -181,7 +144,7 @@ def test_fused_recurrence_is_chosen_from_spec_impl_and_platform(
            "hybrid": HybridForecasterConfig()}[spec]
     if tpu:
         monkeypatch.setattr(platform, "on_tpu", lambda: True)
-    assert forecaster.fused_recurrence(cfg, cell_impl) is want
+    assert forecaster.fused_recurrence(cfg) is want
 
 
 def test_forecast_forward_is_the_scan_where_fused(monkeypatch):
@@ -230,7 +193,7 @@ def test_mesh_round_fused_matches_scan(aggregation, monkeypatch):
         return params, float(loss)
 
     p_scan, l_scan = round_()
-    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c, i: True)
+    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c: True)
     p_fused, l_fused = round_()
     jax.clear_caches()
     np.testing.assert_allclose(l_fused, l_scan, rtol=F32_TOL)

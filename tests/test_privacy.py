@@ -216,7 +216,7 @@ def test_masked_round_equals_clear_vmap(fl_data):
     from repro.core.async_engine import client_deltas
     d_m, _ = client_deltas(params, x, y, bidx, keys, jnp.float32(0.05),
                            jnp.float32(0.0), FCFG, LOSS, e_mask.transform,
-                           "jnp", e_mask.secure, rk, jnp.asarray(counts))
+                           e_mask.secure, rk, jnp.asarray(counts))
     d_c, _ = client_deltas(params, x, y, bidx, keys, jnp.float32(0.05),
                            jnp.float32(0.0), FCFG, LOSS, e_clear.transform)
     # the mask on the WIRE quantity w_i * y_i has scale mask_std (the
@@ -577,10 +577,10 @@ def test_masked_round_equals_clear_bitwise_vmap(fl_data):
     keys = e_mask.round_keys(0, 4)
     d_m, _ = client_deltas(params, x, y, bidx, keys, jnp.float32(0.05),
                            jnp.float32(0.0), FCFG, LOSS, e_mask.transform,
-                           "jnp", e_mask.secure, rk, jnp.asarray(counts))
+                           e_mask.secure, rk, jnp.asarray(counts))
     d_c, _ = client_deltas(params, x, y, bidx, keys, jnp.float32(0.05),
                            jnp.float32(0.0), FCFG, LOSS, e_clear.transform,
-                           "jnp", None, rk, jnp.asarray(counts))
+                           None, rk, jnp.asarray(counts))
     assert tree_max_abs_diff(d_m, d_c) > 8.0         # masked ≠ clear grid
     for leaf in jax.tree.leaves(d_m):                # b-bit ring symbols
         v = np.asarray(leaf)

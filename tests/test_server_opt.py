@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.configs.base import FLConfig, ForecasterConfig
+from repro.configs.base import FLConfig, ForecasterConfig, TransformConfig
 from repro.core import fedavg, losses, sampling, server_opt
 from repro.core.client import local_update
 from repro.data import partition, synthetic, windows
@@ -39,6 +39,13 @@ def _engine_flcfg(**kw):
                     n_clusters=0, loss="mse", **kw)
 
 
+def _round(params, x, y, bidx, w, lr, mu):
+    """The vmap pipeline round with the identity transform stack."""
+    keys = jnp.zeros((x.shape[0], 2), jnp.uint32)
+    return fedavg.pipeline_round(params, x, y, bidx, w, keys, lr, mu, FCFG,
+                                 LOSS, TransformConfig())
+
+
 # --------------------------------------------------- (a) weighted == uniform
 @given(st.floats(0.5, 8.0), st.integers(0, 2 ** 31 - 1))
 @settings(max_examples=5, deadline=None)
@@ -47,8 +54,9 @@ def test_weighted_aggregate_equal_weights_is_uniform(c, seed):
     stacked = {"a": jnp.asarray(r.normal(size=(5, 3, 2)), jnp.float32),
                "b": [jnp.asarray(r.normal(size=(5, 4)), jnp.float32)]}
     w = jnp.full((5,), c, jnp.float32)
-    tree_close(fedavg.weighted_aggregate(stacked, w),
-               fedavg.fedavg_aggregate(stacked))
+    sums, wsum = fedavg._weighted_sums(stacked, w)
+    tree_close(jax.tree.map(lambda s: s / wsum, sums),
+               jax.tree.map(lambda a: jnp.mean(a, axis=0), stacked))
 
 
 def test_engine_round_equal_counts_matches_uniform_round(fl_data):
@@ -56,8 +64,9 @@ def test_engine_round_equal_counts_matches_uniform_round(fl_data):
     params, x, y, bidx = fl_data
     lr, mu = jnp.float32(0.05), jnp.float32(0.0)
     w = jnp.full((4,), 7.0, jnp.float32)
-    p_w, l_w = fedavg.engine_round(params, x, y, bidx, w, lr, mu, FCFG, LOSS)
-    p_u, l_u = fedavg.fedavg_round(params, x, y, bidx, lr, FCFG, LOSS)
+    p_w, l_w = _round(params, x, y, bidx, w, lr, mu)
+    p_u, l_u = _round(params, x, y, bidx, jnp.ones((4,), jnp.float32), lr,
+                      mu)
     tree_close(p_w, p_u)
     np.testing.assert_allclose(float(l_w), float(l_u), rtol=1e-5)
 
@@ -66,7 +75,7 @@ def test_engine_round_unequal_weights_biases_toward_heavy_client(fl_data):
     params, x, y, bidx = fl_data
     lr, mu = jnp.float32(0.05), jnp.float32(0.0)
     heavy = jnp.asarray([1e4, 1.0, 1.0, 1.0], jnp.float32)
-    p_h, _ = fedavg.engine_round(params, x, y, bidx, heavy, lr, mu, FCFG, LOSS)
+    p_h, _ = _round(params, x, y, bidx, heavy, lr, mu)
     p_0, _ = local_update(params, x[0], y[0], bidx[0], lr, FCFG, LOSS)
     # nearly all weight on client 0 -> aggregate ~= client 0's local model
     tree_close(p_h, p_0, rtol=1e-3, atol=1e-4)

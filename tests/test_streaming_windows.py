@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.configs.base import FLConfig, ForecasterConfig
+from repro.configs.base import FLConfig, ForecasterConfig, TransformConfig
 from repro.core import fedavg, losses, sampling
 from repro.data import partition, synthetic, windows
 from repro.data.windows import ClientWindowProvider
@@ -101,12 +101,15 @@ def test_streamed_training_bit_identical_vmap(seed):
     params = forecaster.init_forecaster(jax.random.PRNGKey(0), FCFG)
     lr, mu = jnp.float32(0.05), jnp.float32(0.0)
     w = jnp.full((4,), float(n_win), jnp.float32)
+    keys = jnp.zeros((4, 2), jnp.uint32)
     x, y, _ = prov.round_batch(sel)
-    p_s, l_s = fedavg.engine_round(params, jnp.asarray(x), jnp.asarray(y),
-                                   jnp.asarray(bidx), w, lr, mu, FCFG, LOSS)
-    p_m, l_m = fedavg.engine_round(params, jnp.asarray(data["x_train"][sel]),
-                                   jnp.asarray(data["y_train"][sel]),
-                                   jnp.asarray(bidx), w, lr, mu, FCFG, LOSS)
+    p_s, l_s = fedavg.pipeline_round(
+        params, jnp.asarray(x), jnp.asarray(y), jnp.asarray(bidx), w, keys,
+        lr, mu, FCFG, LOSS, TransformConfig())
+    p_m, l_m = fedavg.pipeline_round(
+        params, jnp.asarray(data["x_train"][sel]),
+        jnp.asarray(data["y_train"][sel]), jnp.asarray(bidx), w, keys, lr,
+        mu, FCFG, LOSS, TransformConfig())
     assert float(l_s) == float(l_m)
     jax.tree.map(lambda a, b: np.testing.assert_array_equal(a, b), p_s, p_m)
 
@@ -119,14 +122,15 @@ def test_streamed_training_bit_identical_shard_map(equal_series):
                                             FCFG.horizon)
     n_dev = min(2, len(jax.devices()))
     mesh = jax.make_mesh((n_dev,), ("clients",))
-    round_fn = fedavg.make_sharded_engine_round(mesh, FCFG, LOSS)
+    round_fn = fedavg.make_pipeline_round(mesh, FCFG, LOSS)
     sel = np.asarray([0, 3, 1, 5])
     n_win = data["x_train"].shape[1]
     bidx = np.random.default_rng(0).integers(0, n_win, size=(4, 3, 16))
     params = forecaster.init_forecaster(jax.random.PRNGKey(0), FCFG)
     w = jnp.full((4,), float(n_win), jnp.float32)
     x, y, _ = prov.round_batch(sel)
-    args = (jnp.asarray(bidx), w, jnp.float32(0.05), jnp.float32(0.0))
+    args = (jnp.asarray(bidx), w, jnp.zeros((4, 2), jnp.uint32),
+            jnp.float32(0.05), jnp.float32(0.0))
     p_s, l_s = round_fn(params, jnp.asarray(x), jnp.asarray(y), *args)
     p_m, l_m = round_fn(params, jnp.asarray(data["x_train"][sel]),
                         jnp.asarray(data["y_train"][sel]), *args)

@@ -90,39 +90,6 @@ def test_recurrent_cell_compiles_for_v5e(one_chip, cell, B, I):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("cell", ["lstm", "gru"])
-def test_pallas_forecaster_paths_compile_for_v5e(one_chip, monkeypatch, cell):
-    """``cell_impl="pallas"`` through the differentiable wrapper: a vmapped
-    local update of 4 clients at B = 64 and the forecast at bucket 256.
-    Off-chip the platform picks interpret mode, so the test steers the
-    kernels to compile, and drops the traces it made afterwards."""
-    from repro.core import client
-    from repro.kernels import gru_cell as gru_mod, lstm_cell as lstm_mod
-
-    for mod in (lstm_mod, gru_mod):
-        monkeypatch.setattr(mod, "resolve_interpret", lambda i=None: False)
-    fcfg = ForecasterConfig(cell=cell)
-    f32 = jnp.float32
-    params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
-                          forecaster.param_template(fcfg))
-    update = jax.jit(jax.vmap(functools.partial(
-        client.local_update, cfg=fcfg, loss=losses.make_loss("mse"),
-        cell_impl="pallas"), in_axes=(None, 0, 0, 0, None)))
-    try:
-        compiled = update.lower(
-            params, _spec((4, 512, fcfg.lookback, 1), f32, one_chip),
-            _spec((4, 512, fcfg.horizon), f32, one_chip),
-            _spec((4, 8, 64), jnp.int32, one_chip),
-            _spec((), f32, one_chip)).compile()
-        assert "tpu_custom_call" in compiled.as_text()
-        compiled = forecaster.forecast.lower(
-            params, _spec((256, fcfg.lookback, 1), f32, one_chip), cfg=fcfg,
-            cell_impl="pallas").compile()
-        assert "tpu_custom_call" in compiled.as_text()
-    finally:
-        jax.clear_caches()
-
-
 def test_r1_round_compiles_for_v5e(one_chip):
     """The jitted vmap round (local-update of 256 clients x 410 steps of
     B = 64 on a year of windows, identity transform, aggregate) fits one
@@ -140,7 +107,7 @@ def test_r1_round_compiles_for_v5e(one_chip):
             _spec((), f32, one_chip), _spec((), f32, one_chip))
     compiled = fedavg.pipeline_round.lower(
         *args, cfg=FCFG, loss=losses.make_loss("ew_mse", 2.0),
-        tcfg=TransformConfig(), cell_impl="jnp").compile()
+        tcfg=TransformConfig()).compile()
     _fits_one_chip(compiled)
     # the stage scopes survive the TPU compiler, where the trace reads them
     text = compiled.as_text()
@@ -158,7 +125,7 @@ def test_r1_series_round_compiles_for_v5e(one_chip):
                           forecaster.param_template(FCFG))
     L, Hz = FCFG.lookback, FCFG.horizon
     kw = dict(cfg=FCFG, loss=losses.make_loss("ew_mse", 2.0),
-              tcfg=TransformConfig(), cell_impl="jnp")
+              tcfg=TransformConfig())
     rest = (_spec((R1_M, R1_STEPS, R1_BATCH), jnp.int32, one_chip),
             _spec((R1_M,), f32, one_chip),
             _spec((R1_M, 2), jnp.uint32, one_chip),
@@ -186,7 +153,7 @@ def test_fused_local_update_compiles_for_v5e(one_chip, monkeypatch, loop):
     from repro.core import client
     from repro.kernels import platform
     monkeypatch.setattr(platform, "on_tpu", lambda: True)
-    assert forecaster.fused_recurrence(FCFG, "jnp")
+    assert forecaster.fused_recurrence(FCFG)
     f32 = jnp.float32
     params = jax.tree.map(lambda a: _spec(a.shape, a.dtype, one_chip),
                           forecaster.param_template(FCFG))

@@ -149,7 +149,7 @@ def test_step_span_names_the_fused_recurrence_where_it_runs(
     """Where the fused kernels differentiate the LSTM (here interpreted,
     the choice steered to them), every round's ``fl.step`` says so."""
     from jax._src.profiler import ProfileData
-    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c, i: True)
+    monkeypatch.setattr(forecaster, "fused_recurrence", lambda c: True)
     flcfg = FLConfig(n_clients=8, clients_per_round=4, rounds=1,
                      local_epochs=1, batch_size=64, n_clusters=0, seed=5)
     jax.clear_caches()
