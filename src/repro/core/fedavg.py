@@ -759,6 +759,13 @@ def run_federated_training(all_series, fcfg: ModelSpec,
     reported on every ``FLResult.heldout_clients``).  Returns
     {cluster_id: FLResult}; cluster_id = -1 when clustering is off.
 
+    The loop is pipelined by one round: after dispatching round t it
+    selects, builds and puts round t+1's inputs while the device runs round
+    t, then waits on round t's loss.  Every host-state mutation (selection
+    and index draws, ``engine.step``) keeps its serial order, so results
+    are bit-identical to preparing each round only after the previous loss
+    (pinned by ``tests/test_round_pipeline.py``).
+
     **Checkpoint/resume** (``checkpoint_path``): every ``checkpoint_every``
     rounds the FULL engine state — params, server-optimizer moments, the
     semi-sync pending buffer (deltas, weights, finish times, cohort re-key
@@ -830,7 +837,8 @@ def run_federated_training(all_series, fcfg: ModelSpec,
     done_acct: Dict[int, Dict] = {}
     executed = 0
 
-    def _save(cid, params, sstate, hist, sim_hist, eps_hist, t_done):
+    def _save(cid, params, sstate, hist, sim_hist, eps_hist, t_done,
+              rng_state):
         tree = {
             "cur": {"params": params,
                     "server": {"m": sstate.m, "v": sstate.v, "t": sstate.t},
@@ -852,7 +860,7 @@ def run_federated_training(all_series, fcfg: ModelSpec,
                 # monotone across clusters, unlike per-cluster rounds_done
                 "generation": int(executed),
                 "done": [int(dc) for dc in results],
-                "rng": rng.bit_generator.state,
+                "rng": rng_state,
                 "accountant": engine.accountant.state_dict(),
                 "done_accountants": {str(dc): done_acct[dc]
                                      for dc in results},
@@ -933,42 +941,51 @@ def run_federated_training(all_series, fcfg: ModelSpec,
         tokens = m_run * steps * ccfg.batch_size * fcfg.lookback
         recurrence = "fused" if forecaster.fused_recurrence(fcfg) else "scan"
         stopped = False
+
+        def _prepare(t, ahead):
+            """Select round t's cohort, draw its minibatch indices, build
+            its series and put them on the device: (device inputs, host
+            weights).  Reads nothing a round writes, so round t+1's inputs
+            are prepared while the device runs round t (``ahead`` = 1)."""
+            with jax.profiler.TraceAnnotation("fl.select", round=t,
+                                              ahead=ahead):
+                # membership churn: absent members sit this round out (pure
+                # function of (seed, round, client id) — replayable).  If
+                # the whole cluster is absent, fall back to full membership
+                # rather than dispatch nothing.  Shapes stay fixed at m_run:
+                # a smaller selection just grows the zero-weight padding.
+                avail = members
+                if engine.latency.churn.absent_prob > 0.0:
+                    mask = engine.latency.available(t, members)
+                    if mask.any():
+                        avail = members[mask]
+                sel = engine.select(rng, avail, min(m_sel, len(avail)), t,
+                                    counts[avail])
+                bidx = partition.ragged_minibatch_indices(
+                    rng, counts[sel], steps, ccfg.batch_size)
+                pad_idx = np.resize(np.arange(len(sel)), m_run)
+            # the round ships the cohort's normalized train series, L + H
+            # times fewer bytes than its windows; the device windows them
+            # (client.minibatches)
+            with jax.profiler.TraceAnnotation(
+                    "fl.round_batch", round=t, clients=m_run,
+                    layout="series") as span:
+                s, c_sel = provider.round_series(sel[pad_idx])
+                span.set_metadata(windows=int(c_sel.sum()), bytes=s.nbytes)
+            w = c_sel.copy()
+            w[len(sel):] = 0.0                        # mask padding clients
+            with jax.profiler.TraceAnnotation("fl.put", round=t) as span:
+                put = engine.put_clients(s, bidx[pad_idx])
+                span.set_metadata(bytes=sum(a.nbytes for a in put))
+            return put, w
+
+        ready = None                     # round t's inputs, prepared ahead
         for t in range(t0, flcfg.rounds):
             # host spans on the profiler's clock (about a microsecond each
             # when no trace is active): a device trace attributes each idle
             # gap of the chip to the host stage that left it idle
             with jax.profiler.StepTraceAnnotation("fl.round", step_num=t):
-                with jax.profiler.TraceAnnotation("fl.select"):
-                    # membership churn: absent members sit this round out
-                    # (pure function of (seed, round, client id) —
-                    # replayable).  If the whole cluster is absent, fall
-                    # back to full membership rather than dispatch nothing.
-                    # Shapes stay fixed at m_run: a smaller selection just
-                    # grows the zero-weight padding.
-                    avail = members
-                    if engine.latency.churn.absent_prob > 0.0:
-                        mask = engine.latency.available(t, members)
-                        if mask.any():
-                            avail = members[mask]
-                    sel = engine.select(rng, avail, min(m_sel, len(avail)),
-                                        t, counts[avail])
-                    bidx = partition.ragged_minibatch_indices(
-                        rng, counts[sel], steps, ccfg.batch_size)
-                    pad_idx = np.resize(np.arange(len(sel)), m_run)
-                # the round ships the cohort's normalized train series,
-                # L + H times fewer bytes than its windows; the device
-                # windows them (client.minibatches)
-                with jax.profiler.TraceAnnotation(
-                        "fl.round_batch", clients=m_run,
-                        layout="series") as span:
-                    s, c_sel = provider.round_series(sel[pad_idx])
-                    span.set_metadata(windows=int(c_sel.sum()),
-                                      bytes=s.nbytes)
-                w = c_sel.copy()
-                w[len(sel):] = 0.0                    # mask padding clients
-                with jax.profiler.TraceAnnotation("fl.put") as span:
-                    put = engine.put_clients(s, bidx[pad_idx])
-                    span.set_metadata(bytes=sum(a.nbytes for a in put))
+                put, w = ready if ready is not None else _prepare(t, 0)
                 with jax.profiler.TraceAnnotation(
                         "fl.step", client_loop=loop, tokens=tokens,
                         recurrence=recurrence):
@@ -976,6 +993,16 @@ def run_federated_training(all_series, fcfg: ModelSpec,
                         params, sstate, put[0], None, put[1], w, round_idx=t,
                         stream=cid if cid >= 0 else 0)
                 del put          # the device inputs die with their round
+                executed += 1
+                stopped = (stop_after_rounds is not None
+                           and executed >= stop_after_rounds)
+                # a checkpoint of round t holds the rng as round t left it,
+                # so a resume at t+1 replays the draws prepared below
+                rng_state = rng.bit_generator.state
+                # the device runs round t (dispatch is asynchronous) while
+                # the host prepares round t+1; float(l) then waits for it
+                ready = (_prepare(t + 1, 1)
+                         if t + 1 < flcfg.rounds and not stopped else None)
                 with jax.profiler.TraceAnnotation("fl.loss_sync"):
                     hist.append(float(l))
                 sim_hist.append(engine.sim_time)
@@ -986,14 +1013,11 @@ def run_federated_training(all_series, fcfg: ModelSpec,
                     print(f"[cluster {cid}] round {t+1}/{flcfg.rounds} "
                           f"loss {hist[-1]:.5f} sim_t {sim_hist[-1]:.1f}s"
                           f"{eps_s}")
-                executed += 1
-                stopped = (stop_after_rounds is not None
-                           and executed >= stop_after_rounds)
                 if checkpoint_path is not None and (
                         (t + 1) % max(checkpoint_every, 1) == 0
                         or t + 1 == flcfg.rounds or stopped):
                     _save(cid, params, sstate, hist, sim_hist, eps_hist,
-                          t + 1)
+                          t + 1, rng_state)
             if stopped:
                 break
         results[cid] = FLResult(params, np.array(hist),

@@ -1,8 +1,9 @@
 """The round loop's host spans and the round program's stage scopes.
 
 ``run_federated_training`` records one ``fl.round`` step span per round
-holding ``fl.select``, ``fl.round_batch``, ``fl.put``, ``fl.step`` and
-``fl.loss_sync`` (``jax.profiler`` annotations, on the device trace's
+holding its ``fl.step`` and ``fl.loss_sync``; between them, the next
+round's ``fl.select``, ``fl.round_batch`` and ``fl.put``, prepared while the
+device runs this one (``jax.profiler`` annotations, on the device trace's
 clock); ``_pipeline_body`` names its stages with ``jax.named_scope``, which
 reaches the compiled program's ``op_name`` metadata.  Read back here from a
 real profiler trace on the CPU, at a tiny fleet."""
@@ -20,10 +21,11 @@ from repro.data import partition, synthetic, windows
 from repro.models import forecaster
 
 FCFG = ForecasterConfig(cell="lstm", hidden_dim=8)
-FLCFG = FLConfig(n_clients=8, clients_per_round=4, rounds=2, local_epochs=1,
+FLCFG = FLConfig(n_clients=8, clients_per_round=4, rounds=3, local_epochs=1,
                  batch_size=16, n_clusters=0, seed=5)
 CHILDREN = ("fl.select", "fl.round_batch", "fl.put", "fl.step",
             "fl.loss_sync")
+PREPARE = CHILDREN[:3]
 
 
 @pytest.fixture(scope="module")
@@ -51,15 +53,30 @@ def traced(series, tmp_path_factory):
 
 
 def test_one_round_span_per_round_each_holding_every_stage_once(traced):
+    """Each stage appears once per round, and the preparing stages name
+    the round they serve.  Round 0 prepares its own inputs; round t > 0's
+    ``fl.select``, ``fl.round_batch`` and ``fl.put`` sit inside
+    ``fl.round`` t-1, after its ``fl.step`` and before its
+    ``fl.loss_sync``, with ``ahead`` 1; the last round prepares none."""
     _, _, spans = traced
+    n = FLCFG.rounds
     rounds = sorted((s for s in spans if s[2] == "fl.round"),
                     key=lambda s: s[0])
-    assert [r[3]["step_num"] for r in rounds] == list(range(FLCFG.rounds))
-    for s0, e0, _, _ in rounds:
+    assert [r[3]["step_num"] for r in rounds] == list(range(n))
+    for t, (s0, e0, _, _) in enumerate(rounds):
         inside = sorted((s for s in spans if s[2] != "fl.round"
                          and s0 <= s[0] and s[1] <= e0), key=lambda s: s[0])
-        assert [s[2] for s in inside] == list(CHILDREN)
-    assert len(spans) == FLCFG.rounds * (1 + len(CHILDREN))
+        want = [(name, t) for name in PREPARE] if t == 0 else []
+        want.append(("fl.step", None))
+        if t + 1 < n:
+            want += [(name, t + 1) for name in PREPARE]
+        want.append(("fl.loss_sync", None))
+        assert [(s[2], s[3].get("round")) for s in inside] == want
+    assert len(spans) == n * (1 + len(CHILDREN))
+    ahead = {s[3]["round"]: s[3]["ahead"] for s in spans
+             if s[2] == "fl.select"}
+    assert ahead == {t: int(t > 0) for t in range(n)}
+    assert sum(ahead.values()) == n - 1
 
 
 def test_put_span_counts_the_bytes_put(traced, series):
